@@ -21,27 +21,23 @@ parity with one-by-one ``engine.topk`` calls for every request scored
 against the final corpus state.
 
 The D>1 rows re-run the whole comparison against the mesh-sharded engine
-(``XLA_FLAGS=--xla_force_host_platform_device_count=D`` in a subprocess,
-like benchmarks/corpus_shard.py) — same frontend, same invariants, the
-corpus slab split across D devices.
+in the same process, on a mesh over ``jax.devices()[:D]`` (like
+benchmarks/corpus_shard.py: on the CPU the caller sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``) — same frontend,
+same invariants, the corpus slab split across D devices.
 
 Output lines:
     frontend: <D>,<n>,<rate_qps>,<policy>,<p50_ms>,<p95_ms>,<p99_ms>,<qps>,<parity>
+    frontend: <D>,skipped,<n> devices
 with policy ``sync`` or ``b<max_batch>/w<max_wait_ms>``; at offered rates
 above sync capacity the driver FAILS unless coalescing beats sync on both
 p99 and QPS.
 """
 from __future__ import annotations
 
-import os
-import re
-import subprocess
-import sys
 import time
 
 import numpy as np
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 MAX_K = 16
 CHURN_EVERY = 50
@@ -127,8 +123,8 @@ def _run_frontend(engine, ctxs, arrivals, ks, churn, *, max_batch,
     return lat, qps, parity
 
 
-def worker(devices: int, n: int, n_req: int, rate_mults: list[float],
-           batches: list[int]) -> None:
+def leg(devices: int, n: int, n_req: int, rate_mults: list[float],
+        batches: list[int]) -> None:
     import jax
 
     from repro.core.fields import uniform_layout
@@ -138,8 +134,6 @@ def worker(devices: int, n: int, n_req: int, rate_mults: list[float],
     from repro.serving import CorpusRankingEngine
     from repro.serving.corpus import next_pow2
 
-    assert jax.device_count() == devices, \
-        f"forced device count failed: {jax.device_count()} != {devices}"
     layout = uniform_layout(25, 38, 1000)
     cfg = fwfm.FwFMConfig(layout=layout, embed_dim=16, interaction="dplr",
                           rank=3)
@@ -208,46 +202,21 @@ def worker(devices: int, n: int, n_req: int, rate_mults: list[float],
 
 
 def main(quick: bool = False) -> None:
+    import jax
+
     n = 2048 if quick else 8192
     n_req = 150 if quick else 400
     rate_mults = [1.5, 3.0] if quick else [0.7, 1.5, 3.0]
     batches = [8, 32]
-    legs = [(1, n_req), (4, 100 if quick else n_req)]
-    for d, reqs in legs:
-        env = dict(os.environ)
-        # strip any caller-set forced device count (XLA parses the LAST
-        # occurrence, so merely prepending ours would lose to it)
-        inherited = re.sub(r"--xla_force_host_platform_device_count=\d+",
-                           "", env.get("XLA_FLAGS", ""))
-        env["XLA_FLAGS"] = (f"{inherited} "
-                            f"--xla_force_host_platform_device_count={d}"
-                            ).strip()
-        env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep
-                             + env.get("PYTHONPATH", ""))
-        cmd = [sys.executable, "-m", "benchmarks.frontend_latency",
-               "--worker", str(d), "--n", str(n), "--requests", str(reqs),
-               "--rates", ",".join(map(str, rate_mults)),
-               "--batches", ",".join(map(str, batches))]
-        r = subprocess.run(cmd, cwd=REPO, env=env, text=True,
-                           capture_output=True, timeout=1800)
-        sys.stdout.write(r.stdout)
-        if r.returncode != 0:
-            sys.stderr.write(r.stderr[-4000:])
-            raise RuntimeError(f"frontend_latency worker D={d} failed")
+    for d, reqs in [(1, n_req), (4, 100 if quick else n_req)]:
+        if d > jax.device_count():
+            print(f"frontend: {d},skipped,{jax.device_count()} devices",
+                  flush=True)
+            continue
+        leg(d, n, reqs, rate_mults, batches)
 
 
 if __name__ == "__main__":
-    if "--worker" in sys.argv:
-        import argparse
-        ap = argparse.ArgumentParser()
-        ap.add_argument("--worker", type=int, required=True)
-        ap.add_argument("--n", type=int, default=2048)
-        ap.add_argument("--requests", type=int, default=150)
-        ap.add_argument("--rates", default="1.5,3.0")
-        ap.add_argument("--batches", default="8,32")
-        a = ap.parse_args()
-        worker(a.worker, a.n, a.requests,
-               [float(x) for x in a.rates.split(",")],
-               [int(x) for x in a.batches.split(",")])
-    else:
-        main(quick="--quick" in sys.argv)
+    import sys
+
+    main(quick="--quick" in sys.argv)

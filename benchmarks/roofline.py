@@ -3,18 +3,18 @@ terms for every (arch x shape x mesh) cell from the dry-run artifacts.
 
     compute    = HLO_FLOPs / peak_FLOPs            (e.g. 197 TFLOP/s, v5e)
     memory     = HLO_bytes / HBM_bw                (819 GB/s)
-    collective = collective_bytes / link_bw        (50 GB/s/link ICI)
+    collective = collective_bytes / ICI_bw         (1,600 Gbit/s, v5e)
 
 All inputs are PER-DEVICE (the compiled HLO is the per-device program;
 launch/hlo_cost.py multiplies while-loop trip counts, which XLA's own
 cost_analysis does not).  The bottleneck is the max term; the "useful
 fraction" MODEL_FLOPS / HLO_FLOPs exposes remat/dispatch/masking waste.
 
-The per-chip peak numbers come from the NAMED hardware-profile table in
-``repro.kernels.autotune.HW_PROFILES`` — one source shared with the
-kernel autotuner — selected by ``--hw`` (default v5e).  The module-level
-``PEAK_FLOPS``/``HBM_BW``/``ICI_BW`` names remain as the active
-profile's bindings for backward compatibility; ``set_hw`` rebinds them.
+The per-chip peak numbers come from ``repro.kernels.autotune.HW_PROFILES``
+— one table shared with the kernel autotuner, keyed by ``device_kind`` —
+for the device this process runs on; ``--hw KIND`` overrides it
+explicitly (the dry-run records describe a chip that need not be
+attached).  A device kind the table does not hold is an error.
 """
 from __future__ import annotations
 
@@ -27,27 +27,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
-from repro.kernels.autotune import DEFAULT_HW, HW_PROFILES
-
-# Active-profile bindings (back-compat names; see set_hw).
-PEAK_FLOPS = HW_PROFILES[DEFAULT_HW]["peak_flops"]   # bf16 per chip
-HBM_BW = HW_PROFILES[DEFAULT_HW]["hbm_bw"]           # bytes/s per chip
-ICI_BW = HW_PROFILES[DEFAULT_HW]["ici_bw"]           # bytes/s per link
-ACTIVE_HW = DEFAULT_HW
-
-
-def set_hw(name: str) -> None:
-    """Select the active hardware profile (rebinds the module constants
-    every term below reads)."""
-    global PEAK_FLOPS, HBM_BW, ICI_BW, ACTIVE_HW
-    if name not in HW_PROFILES:
-        raise ValueError(f"unknown hw profile {name!r}; "
-                         f"have {sorted(HW_PROFILES)}")
-    prof = HW_PROFILES[name]
-    PEAK_FLOPS = prof["peak_flops"]
-    HBM_BW = prof["hbm_bw"]
-    ICI_BW = prof["ici_bw"]
-    ACTIVE_HW = name
+from repro.kernels.autotune import HW_PROFILES, hw_profile
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results",
                            "dryrun")
@@ -170,11 +150,11 @@ def hbm_bytes(rec: dict) -> float:
     return min(candidates)
 
 
-def analyze_record(rec: dict) -> dict:
+def analyze_record(rec: dict, peaks: dict) -> dict:
     n_dev = rec["n_devices"]
-    compute_s = rec["flops"] / PEAK_FLOPS
-    memory_s = hbm_bytes(rec) / HBM_BW
-    coll_s = rec["collectives"]["total_bytes"] / ICI_BW
+    compute_s = rec["flops"] / peaks["peak_flops"]
+    memory_s = hbm_bytes(rec) / peaks["hbm_bw"]
+    coll_s = rec["collectives"]["total_bytes"] / peaks["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     bottleneck = max(terms, key=terms.get)
     step_s = max(terms.values())
@@ -193,14 +173,15 @@ def analyze_record(rec: dict) -> dict:
     }
 
 
-def load_all(mesh: str = "single", include_tagged: bool = False):
+def load_all(peaks: dict, mesh: str = "single",
+             include_tagged: bool = False):
     rows = []
     for f in sorted(glob.glob(os.path.join(RESULTS_DIR, f"*__{mesh}.json"))):
         if "+" in os.path.basename(f) and not include_tagged:
             continue   # optimized §Perf variants live in their own table
         rec = json.load(open(f))
         if rec.get("ok"):
-            rows.append(analyze_record(rec))
+            rows.append(analyze_record(rec, peaks))
     return rows
 
 
@@ -218,12 +199,12 @@ def render_markdown(rows) -> str:
 
 
 def main(quick: bool = False, hw: str | None = None):
-    if hw is not None:
-        set_hw(hw)
-    print(f"roofline: hw profile {ACTIVE_HW} "
-          f"(peak {PEAK_FLOPS/1e12:.0f} TFLOP/s, HBM {HBM_BW/1e9:.0f} "
-          f"GB/s, ICI {ICI_BW/1e9:.0f} GB/s)")
-    rows = load_all("single")
+    peaks = hw_profile(hw)
+    print(f"roofline: peaks of {hw or 'this device'} "
+          f"(peak {peaks['peak_flops']/1e12:.0f} TFLOP/s, HBM "
+          f"{peaks['hbm_bw']/1e9:.0f} GB/s, ICI {peaks['ici_bw']/1e9:.0f} "
+          f"GB/s)")
+    rows = load_all(peaks, "single")
     if not rows:
         print("roofline: no dry-run records found — run "
               "`python -m repro.launch.dryrun` first")
@@ -246,8 +227,8 @@ if __name__ == "__main__":
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--hw", choices=sorted(HW_PROFILES), default=DEFAULT_HW,
-                    help="hardware profile for the peak numbers "
-                         f"(default: {DEFAULT_HW})")
+    ap.add_argument("--hw", choices=sorted(HW_PROFILES), default=None,
+                    help="device kind whose peaks to use (default: the "
+                         "device this process runs on)")
     args = ap.parse_args()
     main(hw=args.hw)

@@ -17,9 +17,11 @@ import time
 import traceback
 
 from benchmarks.registry import BENCHMARKS, describe
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         formatter_class=argparse.RawDescriptionHelpFormatter,
         epilog="benchmarks (run in this order; see docs/benchmarks.md):\n"

@@ -27,10 +27,12 @@ Clamp visibility: candidates larger than ``n`` are clamped by
 on the result so benchmarks report requested-vs-effective divergence
 instead of hiding it (the "no silent caps" rule).
 
-``HW_PROFILES`` is the single named source of per-chip peak numbers —
-``benchmarks/roofline.py`` binds its ``PEAK_FLOPS``/``HBM_BW``/``ICI_BW``
-from here (``--hw`` flag) and the autotuner uses the same profile to
-report each winner's distance from the memory roofline.
+``HW_PROFILES`` is the single source of per-chip peak numbers, keyed by
+``jax.devices()[0].device_kind`` — ``benchmarks/roofline.py`` reads its
+peaks from here and the autotuner uses the same profile to report each
+winner's distance from the memory roofline.  ``hw_profile`` raises for a
+device kind the table does not hold: an unknown chip gets no borrowed
+peaks.
 
 In-process results cache per cell; ``save_cache``/``load_cache``
 round-trip the registry through a small JSON file so a warm process can
@@ -46,18 +48,27 @@ import numpy as np
 
 from repro.kernels import blocks
 
-# Named per-chip peak numbers (public spec-sheet values; bf16 FLOPs).
-# The profile feeds both the roofline benchmark and the autotuner's
-# bandwidth reporting — one table, two consumers.
+# Per-chip peaks keyed by ``device_kind``: bf16 FLOP/s, HBM bytes/s and
+# chip-to-chip interconnect bytes/s.  Source: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI.  Add a
+# chip here, with its source, before measuring on it.
 HW_PROFILES: dict[str, dict[str, float]] = {
-    "v5e": {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
-    "v4": {"peak_flops": 275e12, "hbm_bw": 1228e9, "ici_bw": 100e9},
-    "v5p": {"peak_flops": 459e12, "hbm_bw": 2765e9, "ici_bw": 100e9},
-    # interpret-mode CPU numbers are deliberately rough: the autotuner
-    # only uses them for reporting, never for picking a winner
-    "cpu": {"peak_flops": 1e11, "hbm_bw": 5e10, "ici_bw": 1e9},
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9,
+                    "ici_bw": 1600e9 / 8},
 }
-DEFAULT_HW = "v5e"
+
+
+def hw_profile(kind: str | None = None) -> dict[str, float]:
+    """Peaks of ``kind`` (default: this process's first device).  A
+    device kind the table does not hold raises — no default profile."""
+    if kind is None:
+        import jax
+        kind = jax.devices()[0].device_kind
+    if kind not in HW_PROFILES:
+        raise ValueError(f"no peak rates for device kind {kind!r}; "
+                         f"have {sorted(HW_PROFILES)}")
+    return HW_PROFILES[kind]
+
 
 # Default tile sweep: the named default plus its pow2 neighbours.  Cells
 # smaller than a candidate clamp (visibly — see clamp events).
@@ -85,7 +96,7 @@ class TunedTile:
     us: float                   # winner's time
     default_us: float           # CORPUS_TILE_N/f32 time on the same cell
     swept: tuple = ()           # every CandidateResult, winners and losers
-    hw: str = DEFAULT_HW
+    device_kind: str = ""       # the device the sweep ran on
     bytes_per_call: int = 0     # slab + output traffic, for roofline frac
 
     @property
@@ -94,9 +105,9 @@ class TunedTile:
 
     @property
     def roofline_frac(self) -> float:
-        """Fraction of the profile's HBM roofline the winner achieves
-        (reporting only — meaningless in interpret mode, honest on TPU)."""
-        bw = HW_PROFILES[self.hw]["hbm_bw"]
+        """Fraction of the HBM roofline of the device the sweep ran on
+        (raises where that device has no profile, e.g. the CPU)."""
+        bw = hw_profile(self.device_kind)["hbm_bw"]
         ideal_us = self.bytes_per_call / bw * 1e6
         return ideal_us / self.us if self.us > 0 else 0.0
 
@@ -162,7 +173,7 @@ def tune_corpus_score(n: int, rho: int, k: int, Bq: int, K: int, *,
                       sweep_bf16_acc: bool | None = None,
                       bf16_tol: float = 5e-2,
                       repeats: int = 3, seed: int = 0,
-                      register: bool = True, hw: str = DEFAULT_HW,
+                      register: bool = True,
                       interpret: bool | None = None) -> TunedTile:
     """Sweep ``dplr_corpus_score`` tiles for one shape cell and return
     the parity-gated winner (registered into ``blocks`` unless
@@ -180,9 +191,6 @@ def tune_corpus_score(n: int, rho: int, k: int, Bq: int, K: int, *,
     from repro.kernels import ops
     from repro.kernels.ref import dplr_corpus_score_ref, dplr_corpus_topk_ref
 
-    if hw not in HW_PROFILES:
-        raise ValueError(f"unknown hw profile {hw!r}; "
-                         f"have {sorted(HW_PROFILES)}")
     # the cell's backend key MUST be what ops._resolve_tile uses at the
     # real call sites, or registered winners would never be looked up
     cell = blocks.tile_cell(n, rho, k, Bq, K, dtype, jax.default_backend())
@@ -205,8 +213,15 @@ def tune_corpus_score(n: int, rho: int, k: int, Bq: int, K: int, *,
         sweep_bf16_acc = jnp.dtype(dtype) == jnp.bfloat16
     accs = ("float32", "bfloat16") if sweep_bf16_acc else ("float32",)
 
+    default_bn = blocks.CORPUS_TILE_N
+    if ops._on_tpu() if interpret is None else not interpret:
+        # compiled by Mosaic: a tile over the scoped-VMEM rule would not
+        # compile, so the sweep stays inside it
+        default_bn = blocks.corpus_vmem_tile(default_bn, Bq)
+        candidates = [bn for bn in candidates
+                      if bn == blocks.corpus_vmem_tile(bn, Bq)]
     sweep = dict.fromkeys(candidates)       # ordered, deduped
-    sweep[blocks.CORPUS_TILE_N] = None      # the default always competes
+    sweep[default_bn] = None                # the default always competes
     results: list[CandidateResult] = []
     for bn in sweep:
         for acc in accs:
@@ -230,14 +245,15 @@ def tune_corpus_score(n: int, rho: int, k: int, Bq: int, K: int, *,
             f"autotune cell {cell}: no candidate passed the parity gate")
     winner = min(passing, key=lambda r: r.us)
     default_us = min(r.us for r in passing
-                     if r.block_n == blocks.CORPUS_TILE_N
+                     if r.block_n == default_bn
                      and r.acc_dtype == "float32")
     itemsize = jnp.dtype(dtype).itemsize
     slab_bytes = n * rho * k * itemsize + n * (itemsize + 1)
     out_bytes = Bq * K * 8 + Bq * rho * k * itemsize
     tuned = TunedTile(cell=cell, block_n=winner.block_n,
                       acc_dtype=winner.acc_dtype, us=winner.us,
-                      default_us=default_us, swept=tuple(results), hw=hw,
+                      default_us=default_us, swept=tuple(results),
+                      device_kind=jax.devices()[0].device_kind,
                       bytes_per_call=slab_bytes + out_bytes)
     _RESULTS[cell] = tuned
     if register:

@@ -37,6 +37,7 @@ from __future__ import annotations
 import functools
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Named default tile sizes (retune here, not at call sites).  Values are
 # VMEM-budget choices for the f32 shapes documented in each kernel.
@@ -44,6 +45,13 @@ CORPUS_TILE_N = 2048    # dplr_corpus_score: item-axis tile of (n, rho, k)
 ITEM_TILE_N = 1024      # dplr_score_items: item-axis tile of (n, mI, k)
 PAIRWISE_TILE_B = 512   # fwfm_pairwise: example-axis tile of (B, m, k)
 ATTN_TILE = 128         # flash_attention: q/k row tile (MXU lane width)
+
+# Scoped-VMEM rule of the corpus scorer.  Its (rows, block_n, rho, k) f32
+# intermediate pads the minor (rho, k) dims to one (8, 128) tile: 4 KiB per
+# (query row, item) pair.  The TPU compiler's default scoped VMEM (16 MiB
+# on v5e) holds 4096 such pairs: rows x block_n = 4096 compiles at every
+# rows in 1..16 for the v5e, and 8192 runs out of VMEM.
+CORPUS_VMEM_PAIRS = 4096
 
 # Bounded log of tile clamps (requested > axis length).  Appended at
 # trace time by clamp_tile; drained by the autotuner / benchmarks.
@@ -79,6 +87,16 @@ _TUNED_TILES: dict[tuple, tuple[int, str]] = {}
 # newest winner per shape family (n, rho, k, dtype, backend), used when a
 # call's (Bq, K) cell was never tuned directly
 _TUNED_FAMILY: dict[tuple, tuple[int, str]] = {}
+
+
+def corpus_vmem_tile(block_n: int, rows: int) -> int:
+    """The largest power-of-two corpus tile <= ``block_n`` whose
+    ``(rows, tile, rho, k)`` working set fits ``CORPUS_VMEM_PAIRS``
+    (never below 8, the f32 sublane count).  ``rows`` is the query rows
+    one launch scores: Bq, or S * Bq for the multi-segment kernel."""
+    cap = max(8, 1 << (max(CORPUS_VMEM_PAIRS // max(rows, 1), 1)
+                       .bit_length() - 1))
+    return min(block_n, cap)
 
 
 def tile_cell(n: int, rho: int, k: int, Bq: int, K: int | None,
@@ -154,6 +172,13 @@ def broadcast(*shape: int) -> pl.BlockSpec:
     running top-K output blocks carried across steps)."""
     zeros = (0,) * len(shape)
     return pl.BlockSpec(tuple(shape), lambda i: zeros)
+
+
+def smem() -> pl.BlockSpec:
+    """A whole small operand placed in SMEM (scalar memory) — runtime
+    scalars a kernel reads as ``ref[j]``, such as a traced index offset.
+    A (1, 1) VMEM block would be padded to a full (8, 128) tile."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def attn_tiles(block_rows: int, head_dim: int, *, kv: bool) -> pl.BlockSpec:

@@ -24,10 +24,11 @@ Two output modes:
   * top-K  — running per-query top-K carried in the OUTPUT blocks across
     grid steps (constant index_map => the block stays resident in VMEM);
     each step merges its tile's scores into the running (values, indices)
-    pair, so only ``(Bq, K)`` floats + ints ever leave the scorer.  The
-    merge uses ``jax.lax.top_k`` on the ``(Bq, K + block_n)`` concat —
-    supported in interpret mode; on Mosaic a bitonic merge may be needed
-    for very old toolchains.
+    pair, so only ``(Bq, K)`` floats + ints ever leave the scorer.  Mosaic
+    has no ``top_k`` lowering, so the merge (``_merge_topk``) is K rounds
+    of row max + lowest-position select over ``[running, tile]`` — the
+    exact ``lax.top_k`` contract on that concat: rows best-first, ties to
+    the lowest position (running entries first, then the tile in order).
 
 Validity mask: the serving corpus is a capacity-padded MUTABLE slab
 (``repro.serving.corpus``), so the kernel takes an optional ``valid`` (n,)
@@ -57,13 +58,15 @@ knob only for bf16 slabs; scores are tolerance-gated, not bit-exact.
 Multi-segment mode: ``dplr_corpus_score_multi`` scores S tenants'
 micro-batches in ONE launch.  The per-segment corpus slabs concatenate
 on the item axis (each padded to a whole number of tiles), the S
-micro-batches stack into one (S*Bq, ...) context block, and a static
-per-tile ``(q_off, q_len, row_base)`` table tells each grid step which
-query rows its tile's segment owns: rows outside the window are pinned
-to NEG_INF before the running top-K merge, so a segment's top-K can
-NEVER surface a neighbor segment's slot, and emitted indices are
-segment-LOCAL (``row_base`` restarts at 0 per segment) relabeled by the
-same ``index_offset``/``index_stride`` rule as the single-tenant mode.
+micro-batches stack into one (S*Bq, ...) context block, and each grid
+step derives its segment from ``program_id`` against the STATIC per-
+segment tile boundaries (trace-time constants — no metadata operand):
+rows outside the segment's query window are pinned to NEG_INF before the
+running top-K merge, so a segment's top-K can NEVER surface a neighbor
+segment's slot, and emitted indices are segment-LOCAL (the row base
+restarts at 0 per segment) relabeled by the same
+``index_offset``/``index_stride`` rule as the single-tenant mode.
+``index_offset`` is a scalar read from SMEM in both modes.
 """
 from __future__ import annotations
 
@@ -78,13 +81,14 @@ from repro.kernels import blocks
 NEG_INF = -1e30
 
 
-def _einsum_acc(spec, pp, e, acc_dtype):
-    """The eigen-weighted reduction, in the requested accumulation dtype
-    (f32 path untouched — bit-identical to the historical kernel)."""
-    if acc_dtype == jnp.float32:
-        return jnp.einsum(spec, pp, e)
-    return jnp.einsum(spec, pp.astype(acc_dtype),
-                      e.astype(acc_dtype)).astype(jnp.float32)
+def _rank_reduce(pp, e, acc_dtype):
+    """``sum_{r,k} e[..., r] * pp[..., r, k]`` over the trailing (rho, k)
+    axes, in the requested accumulation dtype, as VPU multiplies and
+    sums: an in-kernel matmul would run at the TPU's default f32 matmul
+    precision (a single bf16 pass), far outside the f32 contract.
+    ``e`` broadcasts as ``(rows, 1, rho)``."""
+    sq = jnp.sum(pp.astype(acc_dtype), axis=-1)            # (q, n, rho)
+    return jnp.sum(sq * e.astype(acc_dtype), axis=-1).astype(jnp.float32)
 
 
 def _tile_scores(q, a_i, e, pc, a_c, m, acc_dtype=jnp.float32):
@@ -94,7 +98,7 @@ def _tile_scores(q, a_i, e, pc, a_c, m, acc_dtype=jnp.float32):
     # p: (Bq, bn, rho, k) — direct fused form, same reduction order as the
     # jnp reference so corpus-cached parity stays at float32 epsilon.
     p = pc[:, None, :, :] + q[None, :, :, :]
-    term_e = _einsum_acc("qnrk,r->qn", p * p, e, acc_dtype)
+    term_e = _rank_reduce(p * p, e[None, None, :], acc_dtype)
     s = a_c[:, None] + a_i[None, :] + 0.5 * term_e
     return jnp.where((m != 0)[None, :], s, NEG_INF)
 
@@ -106,9 +110,51 @@ def _kernel_full(q_ref, a_ref, e_ref, pc_ref, ac_ref, m_ref, out_ref, *,
         m_ref[:, 0], acc_dtype)
 
 
+def _merge_topk(val_ref, idx_ref, scores, labels):
+    """Merge one tile's ``(Bq, block_n)`` scores (with their int32 index
+    labels) into the running ``(Bq, K)`` top-K held in ``val_ref`` /
+    ``idx_ref``: ``lax.top_k`` over the concat ``[running, tile]``, built
+    from primitives Mosaic lowers.  Round ``r`` takes each row's max, picks
+    the LOWEST concat position holding it (running entries first, then
+    the tile's rows in order — the top_k tie rule), writes it to column
+    ``r`` and retires it to ``-inf`` (below every live score and below
+    the NEG_INF dead-slot sentinel, so a retired entry is never re-picked
+    while untaken candidates remain)."""
+    run_v, run_i = val_ref[...], idx_ref[...]
+    K = run_v.shape[1]
+    pos_r = jax.lax.broadcasted_iota(jnp.int32, run_v.shape, 1)
+    pos_t = K + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    past = K + scores.shape[1]               # one past the last position
+    lowest = jnp.iinfo(jnp.int32).min
+
+    def row_max(a, b):
+        return jnp.maximum(jnp.max(a, axis=1, keepdims=True),
+                           jnp.max(b, axis=1, keepdims=True))
+
+    def pick(r, carry):
+        cand_r, cand_t, out_v, out_i = carry
+        m = row_max(cand_r, cand_t)
+        p = jnp.minimum(
+            jnp.min(jnp.where(cand_r == m, pos_r, past), axis=1,
+                    keepdims=True),
+            jnp.min(jnp.where(cand_t == m, pos_t, past), axis=1,
+                    keepdims=True))
+        lab = row_max(jnp.where(pos_r == p, run_i, lowest),
+                      jnp.where(pos_t == p, labels, lowest))
+        col = pos_r == r
+        return (jnp.where(pos_r == p, -jnp.inf, cand_r),
+                jnp.where(pos_t == p, -jnp.inf, cand_t),
+                jnp.where(col, m, out_v), jnp.where(col, lab, out_i))
+
+    _, _, top_v, top_i = jax.lax.fori_loop(
+        0, K, pick, (run_v, scores, run_v, run_i))
+    val_ref[...] = top_v
+    idx_ref[...] = top_i
+
+
 def _kernel_topk(q_ref, a_ref, e_ref, pc_ref, ac_ref, m_ref, off_ref,
-                 val_ref, idx_ref, *, block_n: int, topk: int,
-                 index_stride: int, acc_dtype):
+                 val_ref, idx_ref, *, block_n: int, index_stride: int,
+                 acc_dtype):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -121,13 +167,9 @@ def _kernel_topk(q_ref, a_ref, e_ref, pc_ref, ac_ref, m_ref, off_ref,
         m_ref[:, 0], acc_dtype)
     # row r of this tile is local slot i*block_n + r; the emitted index is
     # its caller-defined global label off + stride * local.
-    tile_idx = off_ref[0, 0] + index_stride * (
+    labels = off_ref[0] + index_stride * (
         i * block_n + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-    cat_v = jnp.concatenate([val_ref[...], scores], axis=1)
-    cat_i = jnp.concatenate([idx_ref[...], tile_idx], axis=1)
-    top_v, top_pos = jax.lax.top_k(cat_v, topk)
-    val_ref[...] = top_v
-    idx_ref[...] = jnp.take_along_axis(cat_i, top_pos, axis=1)
+    _merge_topk(val_ref, idx_ref, scores, labels)
 
 
 @functools.partial(jax.jit,
@@ -204,10 +246,10 @@ def dplr_corpus_score(
 
     if not 0 < topk <= n:
         raise ValueError(f"topk={topk} out of range for n={n}")
-    off = jnp.asarray(index_offset, jnp.int32).reshape(1, 1)
-    in_specs = in_specs + [blocks.broadcast(1, 1)]
+    off = jnp.asarray(index_offset, jnp.int32).reshape(1)
+    in_specs = in_specs + [blocks.smem()]
     args = args + (off,)
-    kernel = functools.partial(_kernel_topk, block_n=block_n, topk=topk,
+    kernel = functools.partial(_kernel_topk, block_n=block_n,
                                index_stride=index_stride, acc_dtype=acc)
     return pl.pallas_call(
         kernel,
@@ -237,14 +279,15 @@ def _tile_scores_multi(q, a_i, e_q, pc, a_c, m, acc_dtype=jnp.float32):
     weights, so foreign rows compute garbage that the caller masks to
     NEG_INF before the merge (they can never win a slot)."""
     p = pc[:, None, :, :] + q[None, :, :, :]
-    term_e = _einsum_acc("qnrk,qr->qn", p * p, e_q, acc_dtype)
+    term_e = _rank_reduce(p * p, e_q[:, None, :], acc_dtype)
     s = a_c[:, None] + a_i[None, :] + 0.5 * term_e
     return jnp.where((m != 0)[None, :], s, NEG_INF)
 
 
-def _kernel_multi_topk(q_ref, a_ref, m_ref, meta_ref, eq_ref, pc_ref,
-                       ac_ref, off_ref, val_ref, idx_ref, *, topk: int,
-                       index_stride: int, acc_dtype):
+def _kernel_multi_topk(q_ref, a_ref, m_ref, eq_ref, pc_ref, ac_ref,
+                       off_ref, val_ref, idx_ref, *, seg_tiles: tuple,
+                       Bq: int, block_n: int, index_stride: int,
+                       acc_dtype):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -255,21 +298,22 @@ def _kernel_multi_topk(q_ref, a_ref, m_ref, meta_ref, eq_ref, pc_ref,
     scores = _tile_scores_multi(
         q_ref[...], a_ref[:, 0], eq_ref[...], pc_ref[...], ac_ref[:, 0],
         m_ref[:, 0], acc_dtype)
-    # the tile's static metadata row: which stacked query rows this
-    # tile's segment owns, and the tile's first segment-LOCAL item row
-    q_off, q_len, row_base = meta_ref[0, 0], meta_ref[0, 1], meta_ref[0, 2]
+    # which segment this tile belongs to, from the static tile boundaries:
+    # its stacked query rows [q_off, q_off + Bq) and its first segment-
+    # LOCAL item row
+    q_off, row_base = 0, 0
+    for s, (first, stop) in enumerate(seg_tiles):
+        in_seg = (i >= first) & (i < stop)
+        q_off = jnp.where(in_seg, s * Bq, q_off)
+        row_base = jnp.where(in_seg, (i - first) * block_n, row_base)
     qidx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-    own = (qidx >= q_off) & (qidx < q_off + q_len)
+    own = (qidx >= q_off) & (qidx < q_off + Bq)
     # a foreign row sees this tile as all-NEG_INF, so its running top-K
     # is untouched by neighbor segments' item tiles (segment isolation)
     scores = jnp.where(own, scores, NEG_INF)
-    tile_idx = off_ref[0, 0] + index_stride * (
+    labels = off_ref[0] + index_stride * (
         row_base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-    cat_v = jnp.concatenate([val_ref[...], scores], axis=1)
-    cat_i = jnp.concatenate([idx_ref[...], tile_idx], axis=1)
-    top_v, top_pos = jax.lax.top_k(cat_v, topk)
-    val_ref[...] = top_v
-    idx_ref[...] = jnp.take_along_axis(cat_i, top_pos, axis=1)
+    _merge_topk(val_ref, idx_ref, scores, labels)
 
 
 @functools.partial(jax.jit,
@@ -294,8 +338,8 @@ def dplr_corpus_score_multi(
     ONE kernel launch and returns ``((S, Bq, topk) scores, (S, Bq, topk)
     int32 indices)`` — row ``[s, q]`` is bitwise the running top-K of
     segment ``s``'s corpus alone (foreign tiles contribute only NEG_INF,
-    which ``lax.top_k``'s lowest-position tie-break can never promote
-    over a live score while ``topk <= n_live(s)``).
+    which the merge's lowest-position tie-break can never promote over a
+    live score while ``topk <= n_live(s)``).
 
     Indices are segment-LOCAL slots relabeled by ``index_offset``/
     ``index_stride`` (same striping rule as the single-segment mode, so
@@ -303,9 +347,9 @@ def dplr_corpus_score_multi(
 
     The per-segment corpus slabs concatenate on the item axis, each
     padded to a whole number of ``block_n`` tiles with phantom dead
-    rows; a static per-tile ``(q_off, q_len, row_base)`` int32 table —
-    trace-time metadata, one row per grid step via a ``row_tiles(1, 3)``
-    spec — windows each tile to its own segment's stacked query rows.
+    rows; each grid step finds its segment from ``program_id`` and the
+    static per-segment tile boundaries, and windows the tile to that
+    segment's stacked query rows.
     Retrace keying: the tuple length S is part of the pytree structure,
     so callers bucket S (the frontend pads to power-of-two segment
     counts) exactly like Bq and K."""
@@ -331,7 +375,7 @@ def dplr_corpus_score_multi(
     block_n = blocks.clamp_tile(block_n, max(int(q.shape[0])
                                              for q in Q_parts))
 
-    q_cat, a_cat, m_cat, meta = [], [], [], []
+    q_cat, a_cat, m_cat, seg_tiles = [], [], [], []
     for s in range(S):
         q_s = Q_parts[s].astype(jnp.float32)
         a_s = a_parts[s].astype(jnp.float32)
@@ -346,32 +390,31 @@ def dplr_corpus_score_multi(
         q_cat.append(q_s)
         a_cat.append(a_s)
         m_cat.append(m_s)
-        for j in range((n_s + pad) // block_n):
-            meta.append((s * Bq, Bq, j * block_n))
+        first = seg_tiles[-1][1] if seg_tiles else 0
+        seg_tiles.append((first, first + (n_s + pad) // block_n))
     Q_cat = jnp.concatenate(q_cat)
     a_cat = jnp.concatenate(a_cat)
     m_cat = jnp.concatenate(m_cat)
-    meta = jnp.asarray(meta, jnp.int32)          # (n_tiles, 3), static
     grid = blocks.grid_1d(Q_cat.shape[0], block_n)
 
     e_q = jnp.repeat(e.astype(jnp.float32), Bq, axis=0)        # (SB, rho)
     pc = P_C.astype(jnp.float32).reshape(SB, rho, k)
     ac = a_C.astype(jnp.float32).reshape(SB)
-    off = jnp.asarray(index_offset, jnp.int32).reshape(1, 1)
+    off = jnp.asarray(index_offset, jnp.int32).reshape(1)
 
     in_specs = [
         blocks.row_tiles(block_n, rho, k),
         blocks.row_tiles(block_n, 1),
         blocks.row_tiles(block_n, 1),
-        blocks.row_tiles(1, 3),
         blocks.broadcast(SB, rho),
         blocks.broadcast(SB, rho, k),
         blocks.broadcast(SB, 1),
-        blocks.broadcast(1, 1),
+        blocks.smem(),
     ]
-    args = (Q_cat, a_cat[:, None], m_cat[:, None], meta, e_q, pc,
-            ac[:, None], off)
-    kernel = functools.partial(_kernel_multi_topk, topk=topk,
+    args = (Q_cat, a_cat[:, None], m_cat[:, None], e_q, pc, ac[:, None],
+            off)
+    kernel = functools.partial(_kernel_multi_topk, seg_tiles=tuple(seg_tiles),
+                               Bq=Bq, block_n=block_n,
                                index_stride=index_stride, acc_dtype=acc)
     vals, idx = pl.pallas_call(
         kernel,

@@ -1,7 +1,7 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (Pallas has no
-CPU lowering); on TPU ``interpret=False`` compiles through Mosaic.  The
+Off the TPU the kernels execute in interpret mode (Pallas has no CPU
+lowering); on TPU ``interpret=False`` compiles through Mosaic.  The
 wrappers pick that automatically and expose the same signatures as the
 pure-jnp references, so the serving stack can swap implementations with a
 flag (cfg.use_pallas_kernels).
@@ -38,13 +38,16 @@ def dplr_score_items(V_I, U_I, e, d_I, P_C, s_C, *,
                                   block_n=block_n, interpret=interp)
 
 
-def _resolve_tile(n, rho, k, Bq, K, dtype, block_n, acc_dtype):
+def _resolve_tile(n, rho, k, Bq, K, dtype, block_n, acc_dtype, rows=None):
     """Explicit ``block_n``/``acc_dtype`` win; ``None`` falls through to
-    the autotuner registry (default-identical when nothing is tuned)."""
+    the autotuner registry (default-identical when nothing is tuned),
+    capped by the scoped-VMEM rule for the ``rows`` query rows one launch
+    scores (``blocks.corpus_vmem_tile``; default ``Bq``)."""
     tuned_bn, tuned_acc = blocks.corpus_tile(
         n, rho, k, Bq, K, str(dtype), jax.default_backend())
-    return (tuned_bn if block_n is None else block_n,
-            tuned_acc if acc_dtype is None else acc_dtype)
+    if block_n is None:
+        block_n = blocks.corpus_vmem_tile(tuned_bn, rows or Bq)
+    return block_n, tuned_acc if acc_dtype is None else acc_dtype
 
 
 def dplr_corpus_score(Q_I, a_I, e, P_C, a_C, valid=None, *, topk=None,
@@ -76,7 +79,8 @@ def dplr_corpus_score_multi(Q_parts, a_parts, valid_parts, e, P_C, a_C, *,
     # dominate the grid); per-segment retuning would fragment block_n
     n, rho, k = max((q.shape for q in Q_parts), key=lambda s: s[0])
     block_n, acc_dtype = _resolve_tile(n, rho, k, P_C.shape[1], topk,
-                                       Q_parts[0].dtype, block_n, acc_dtype)
+                                       Q_parts[0].dtype, block_n, acc_dtype,
+                                       rows=P_C.shape[0] * P_C.shape[1])
     return _corpus.dplr_corpus_score_multi(
         tuple(Q_parts), tuple(a_parts),
         valid_parts if valid_parts is None else tuple(valid_parts),

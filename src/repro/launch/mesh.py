@@ -15,19 +15,34 @@ parallelism and stays inside the pod's ICI domain.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding-in-types
+    default (``Explicit``) rejects the reshapes of shard_map outputs that
+    the serving and training code performs."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
-def make_host_mesh(model: int | None = None):
-    """Host-sized mesh with the production axis names — lets the same
-    pjit/shard_map code paths run in smoke tests / examples on this CPU
-    container.  ``model`` widens the model axis (e.g. the corpus-shard
-    tests run ``model=4`` under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``); default is
-    the classic 1-device (1, 1) mesh."""
-    return jax.make_mesh((1, model or 1), ("data", "model"))
+def make_host_mesh(model: int | None = None, devices=None):
+    """Host-sized mesh with the production axis names.  ``model`` widens
+    the model axis over ``devices`` (default: the first ``model`` of
+    ``jax.devices()``) — e.g. the corpus shards four ways on a four-chip
+    host, or on the CPU under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``; the default
+    is the 1-device (1, 1) mesh."""
+    model = model or 1
+    if devices is None:
+        devices = jax.devices()[:model]
+    if len(devices) != model:
+        raise ValueError(f"a {model}-wide model axis needs {model} devices, "
+                         f"got {len(devices)}")
+    return _mesh((1, model), ("data", "model"), devices)

@@ -109,6 +109,7 @@ from jax.sharding import PartitionSpec as P
 from repro.checkpoint import CheckpointManager
 from repro.configs import REGISTRY
 from repro.data.synthetic_ctr import SyntheticCTR
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.recsys import fwfm
 from repro.serving import (CorpusRankingEngine, RefreshFailed,
@@ -720,6 +721,7 @@ def _chaos_demo(args, engine, data, params) -> None:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dplr-fwfm")
     ap.add_argument("--config", default="smoke", choices=["smoke", "full"])
@@ -935,6 +937,7 @@ def main(argv=None):
                     top = np.argsort(-np.asarray(scores[0]))[:3]
                     print(f"query 0: top-3 of {args.items} candidates -> {top}")
         tag = (f"corpus{', pallas' if args.use_pallas else ''}"
+               f"{' (degraded to jnp)' if engine.kernel_degraded else ''}"
                f"{f', top{args.topk}' if args.topk else ''}"
                f"{f', {n_shards} shards' if n_shards > 1 else ''}"
                f"{', bf16' if args.bf16 else ''}")

@@ -32,6 +32,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import REGISTRY
 from repro.data.pipeline import ShardedPipeline
 from repro.data.synthetic_ctr import SyntheticCTR
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _recsys_module(name):
@@ -88,6 +89,7 @@ def build_lm_trainer(arch_name: str, cfg, batch_size: int, seq: int, seed: int):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dplr-fwfm")
     ap.add_argument("--config", default="smoke", choices=["smoke", "full"])

@@ -72,6 +72,16 @@ from repro.embedding.bag import (
 from repro.kernels.dplr_corpus_score import NEG_INF
 
 
+def serving_precision():
+    """Context for every f32 contraction on the serving path (item rows,
+    context cache, the jnp scorer): full f32 precision.  The TPU's default
+    for an f32 matmul is a single bf16 pass (~1e-3 relative), which would
+    reorder near-tied candidates against the f32 model; the CPU computes
+    f32 either way.  A fresh manager per use: one instance is not
+    reentrant."""
+    return jax.default_matmul_precision("highest")
+
+
 def next_pow2(n: int) -> int:
     """Smallest power of two >= n (>= 1)."""
     return 1 << max(int(n) - 1, 0).bit_length()
@@ -106,16 +116,16 @@ def corpus_rows(params: dict, cfg, item_ids: jax.Array,
     row in a from-scratch rebuild."""
     layout = cfg.layout
     nC = layout.n_context
-    p = DPLRParams(params["U"], params["e"])
-    d = dplr_diagonal(p)
-
-    V_I = lookup_item_embeddings(params["embedding"], layout, item_ids,
-                                 item_weights, take_fn=take_fn)  # (n, mI, k)
-    Q_I = jnp.einsum("rm,...mk->...rk", p.U[:, nC:], V_I)
-    t_I = jnp.einsum("...mk,m->...", V_I * V_I, d[nC:])
-    lin_I = lookup_linear_terms(params["linear"], layout.subset("item"),
-                                item_arena_ids(layout, item_ids),
-                                item_weights, take_fn=take_fn)
+    with serving_precision():
+        p = DPLRParams(params["U"], params["e"])
+        d = dplr_diagonal(p)
+        V_I = lookup_item_embeddings(params["embedding"], layout, item_ids,
+                                     item_weights, take_fn=take_fn)
+        Q_I = jnp.einsum("rm,...mk->...rk", p.U[:, nC:], V_I)  # (n, rho, k)
+        t_I = jnp.einsum("...mk,m->...", V_I * V_I, d[nC:])
+        lin_I = lookup_linear_terms(params["linear"], layout.subset("item"),
+                                    item_arena_ids(layout, item_ids),
+                                    item_weights, take_fn=take_fn)
     return Q_I, t_I, lin_I
 
 
@@ -128,7 +138,8 @@ def masked_slab_scores(params: dict, Q_I, t_I, lin_I, valid,
     the reduction runs over (rho, k) only, which splitting the ITEM axis
     across shards cannot perturb."""
     P = P_C[:, None] + Q_I[None]                       # (Bq, n, rho, k)
-    term_e = jnp.einsum("qnrk,r->qn", P * P, params["e"])
+    with serving_precision():
+        term_e = jnp.einsum("qnrk,r->qn", P * P, params["e"])
     pw = 0.5 * (s_C[:, None] + t_I[None, :] + term_e)
     s = params["bias"] + lin_C[:, None] + lin_I[None, :] + pw
     # dead slots pinned to -inf: they can never win a top-K slot, and the

@@ -85,6 +85,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.serving.corpus import ItemCorpusCache, next_pow2
 from repro.serving.errors import NotReady, RefreshFailed
@@ -100,9 +102,10 @@ class CorpusState:
     Self-healing (see docs/robustness.md): mutations are DEVICE-first so
     a failed churn write leaves the host slab/validity state untouched
     (partial churn is never reader-visible); a Pallas kernel-launch
-    failure degrades stickily to the jnp reference scorer
+    fault degrades stickily to the jnp reference scorer
     (``kernel_degraded`` — bit-exact results, zero new traces when the
-    grid was warmed); ``maybe_refresh`` raises ``RefreshFailed`` on a
+    grid was warmed), while a kernel that fails to lower or compile
+    raises (see ``_kernel_dispatch``); ``maybe_refresh`` raises ``RefreshFailed`` on a
     corrupt newest checkpoint while KEEPING the last-good snapshot live.
     ``fault_injector`` arms the ``write``/``alloc``/``kernel`` chaos
     sites (``repro.serving.faults``)."""
@@ -454,6 +457,11 @@ class CorpusState:
         capacity/D rows (the global-order host slab reshapes to the
         physical (local, D) view for free, because ownership is striped)."""
         self._begin_write()
+        if self.mesh is not None:
+            # one replica per shard, placed once per snapshot: left on one
+            # device, the whole model (embedding arena included) would be
+            # copied to every shard on every sharded dispatch
+            params = jax.device_put(params, NamedSharding(self.mesh, P()))
         self.params = params
         if self.mesh is None:
             self.cache = self.runtime.build(
@@ -551,17 +559,12 @@ class CorpusState:
         self._require_ready()
         ids, w = self._ctx_arrays(context_ids, context_weights)
         if self.use_pallas_kernel and not self.kernel_degraded:
-            try:
-                if self._injector is not None:
-                    self._injector.check("kernel")
-                with scoring_guard():
-                    return self.runtime.kernel_score(self.params,
-                                                     self.cache, ids, w)
-            except Exception:             # noqa: BLE001 — launch failure
-                # Mosaic compile/launch failure: degrade STICKILY to the
-                # jnp reference scorer — bit-exact scores, and zero new
-                # traces when warmup_grid warmed both paths
-                self.kernel_degraded = True
+            out = _kernel_dispatch(
+                (self,), (None, ids.shape),
+                lambda: self.runtime.kernel_score(self.params, self.cache,
+                                                  ids, w))
+            if out is not None:
+                return out
         with scoring_guard():
             return self.runtime.score(self.params, self.cache, ids, w)
 
@@ -586,15 +589,12 @@ class CorpusState:
                 f"live items")
         ids, w = self._ctx_arrays(context_ids, context_weights)
         if self.use_pallas_kernel and not self.kernel_degraded:
-            try:
-                if self._injector is not None:
-                    self._injector.check("kernel")
-                with scoring_guard():
-                    return self.runtime.kernel_score(self.params,
-                                                     self.cache, ids, w,
-                                                     K=K)
-            except Exception:             # noqa: BLE001 — launch failure
-                self.kernel_degraded = True   # sticky; see score()
+            out = _kernel_dispatch(
+                (self,), (K, ids.shape),
+                lambda: self.runtime.kernel_score(self.params, self.cache,
+                                                  ids, w, K=K))
+            if out is not None:
+                return out
         with scoring_guard():
             return self.runtime.topk(self.params, self.cache, ids, w, K=K)
 
@@ -637,6 +637,40 @@ class CorpusState:
         return self.score(query["context_ids"], query.get("context_weights"))
 
 
+def _kernel_dispatch(states, key, launch):
+    """Run one Pallas dispatch for ``states`` (one shared runtime) and
+    return its result, or None after degrading every state STICKILY to
+    the jnp reference path on a launch fault: the armed ``kernel`` fault
+    site, or an error from an executable this runtime has already run at
+    ``key`` (with the states' capacities added to it).  The first dispatch
+    at a key traces, lowers and compiles the kernel, so its error — a
+    Mosaic lowering error, VMEM or HBM exhausted at compile — propagates:
+    a kernel that cannot compile stops the server at warmup instead of
+    hiding behind the jnp path."""
+    rt = states[0].runtime
+    key = key + (tuple(st.capacity for st in states),)
+    try:
+        for st in states:
+            if st._injector is not None:
+                st._injector.check("kernel")
+    except Exception:                     # noqa: BLE001 — injected fault
+        warm = True
+    else:
+        warm = key in rt.kernel_warm
+        try:
+            with scoring_guard():
+                out = launch()
+        except Exception:                 # noqa: BLE001 — see docstring
+            if not warm:
+                raise
+        else:
+            rt.kernel_warm.add(key)
+            return out
+    for st in states:
+        st.kernel_degraded = True
+    return None
+
+
 def fused_topk(states, context_ids, K: int, context_weights=None):
     """ONE device dispatch answering S tenants' micro-batches: returns
     ``((S, Bq, K) scores, (S, Bq, K) int32 slot indices)`` where row
@@ -653,7 +687,7 @@ def fused_topk(states, context_ids, K: int, context_weights=None):
 
     Kernel selection and self-healing mirror ``CorpusState.topk``: the
     Pallas path runs only while NO packed state is kernel-degraded, each
-    state's armed ``kernel`` fault site is checked, and a launch failure
+    state's armed ``kernel`` fault site is checked, and a launch fault
     stickily degrades every packed state to the (bit-exact) jnp fused
     path — a poisoned kernel never splits the pack's fate."""
     states = tuple(states)
@@ -684,16 +718,12 @@ def fused_topk(states, context_ids, K: int, context_weights=None):
     cache_parts = tuple(st.cache for st in states)
     if rt.use_pallas_kernel and not any(st.kernel_degraded
                                         for st in states):
-        try:
-            for st in states:
-                if st._injector is not None:
-                    st._injector.check("kernel")
-            with scoring_guard():
-                return rt.kernel_multi_topk(params_parts, cache_parts,
-                                            ids, w, K=K)
-        except Exception:                 # noqa: BLE001 — launch failure
-            for st in states:             # sticky, pack-wide: see topk()
-                st.kernel_degraded = True
+        out = _kernel_dispatch(
+            states, (K, ids.shape),
+            lambda: rt.kernel_multi_topk(params_parts, cache_parts, ids, w,
+                                         K=K))
+        if out is not None:
+            return out
     with scoring_guard():
         return rt.multi_topk(params_parts, cache_parts, ids, w, K=K)
 

@@ -61,6 +61,7 @@ from repro.serving.corpus import (
     corpus_rows,
     masked_slab_scores,
     next_pow2,
+    serving_precision,
 )
 
 
@@ -99,6 +100,10 @@ class ScorerRuntime:
         self.use_pallas_kernel = use_pallas_kernel
         self.block_n = block_n
         self.trace_count = 0      # incremented only when a scorer retraces
+        # keys of kernel dispatches that have run once: their executables
+        # exist, so a later error there is a launch fault, not a compile
+        # error (engine._kernel_dispatch)
+        self.kernel_warm: set = set()
         if mesh is None:
             self._D = 1
         else:
@@ -173,9 +178,10 @@ class ScorerRuntime:
     def _context_impl(self, params, ctx_ids, ctx_w):
         """Per-query context cache: P_C (Bq, rho, k), s_C (Bq,), lin_C (Bq,)."""
         from repro.models.recsys.fwfm import context_inputs
-        V_C, lin_C = context_inputs(params, self.cfg, ctx_ids, ctx_w)
-        p = DPLRParams(params["U"], params["e"])
-        ctx = rk.dplr_context_cache(p, V_C, self.cfg.layout.n_context)
+        with serving_precision():
+            V_C, lin_C = context_inputs(params, self.cfg, ctx_ids, ctx_w)
+            p = DPLRParams(params["U"], params["e"])
+            ctx = rk.dplr_context_cache(p, V_C, self.cfg.layout.n_context)
         return ctx.P_C, ctx.s_C, lin_C
 
     def _score_impl(self, params, cache, ctx_ids, ctx_w):

@@ -6,25 +6,12 @@ from repro.sharding.rules import (  # noqa: F401
     corpus_slab_axis,
 )
 
-# jax.shard_map landed as a top-level export in jax 0.5; fall back to the
-# experimental home on older runtimes (this container ships 0.4.x).
-try:
-    shard_map = _jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = _jax.shard_map
 
 
 def shard_map_norep(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking disabled.
-
-    Required whenever the body contains a ``pallas_call`` (jax has no
-    replication rule for it, so ``check_rep=True`` — the default — fails at
-    trace time).  The kwarg was renamed ``check_rep`` -> ``check_vma``
-    across jax versions; probe for whichever this runtime accepts.
-    """
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+    """``shard_map`` with replication checking disabled: required whenever
+    the body contains a ``pallas_call`` (jax has no replication rule for
+    it, so ``check_vma=True`` — the default — fails at trace time)."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)

@@ -1,7 +1,7 @@
 """The paper's mathematical core: Identity 1, Proposition 1, and the
 equivalences between all interaction implementations."""
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp

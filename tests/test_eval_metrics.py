@@ -12,7 +12,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.eval import metrics as M
 from repro.eval import ref
 
@@ -43,7 +43,7 @@ def _assert_pointwise_parity(labels, logits, tol=TOL):
 # pointwise metrics vs oracles
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(n=st.integers(1, 4000), seed=st.integers(0, 10**6))
 def test_pointwise_oracle_parity(n, seed):
     labels, logits = _pointwise_case(n, seed)
@@ -130,7 +130,7 @@ def _ranking_case(B: int, n: int, seed: int):
     return rel, scores
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(B=st.integers(1, 16), n=st.integers(1, 128),
        k=st.integers(1, 200), seed=st.integers(0, 10**6))
 def test_ranking_oracle_parity(B, n, k, seed):

@@ -455,6 +455,42 @@ def test_kernel_launch_failure_falls_back_bitexact():
     assert inj.calls("kernel") == 1
 
 
+@pytest.mark.parametrize("warm", [False, True])
+def test_kernel_compile_error_propagates_runtime_fault_degrades(
+        monkeypatch, warm):
+    """A kernel that fails to lower or compile must stop the server, not
+    hide behind the jnp path: the first dispatch at a shape raises out of
+    ``warmup``/``topk`` with ``kernel_degraded`` still False.  The same
+    error from an executable that already ran at that shape is a launch
+    fault and degrades (bit-exact jnp result)."""
+    cfg, params, data, engine = _setup(use_pallas_kernel=True, block_n=16)
+    ctx = np.asarray(_ctx(data, 3)).reshape(1, -1)
+    rt = engine.runtime
+    if warm:
+        engine.warmup_grid(_ctx(data, 0), max_batch=1, max_k=4)
+    good = rt.kernel_score
+
+    def lowering_error(*a, **kw):
+        raise NotImplementedError(
+            "Unimplemented primitive in Pallas TPU lowering: top_k")
+
+    monkeypatch.setattr(rt, "kernel_score", lowering_error)
+    if not warm:
+        with pytest.raises(NotImplementedError):
+            engine.warmup_grid(_ctx(data, 0), max_batch=1, max_k=4)
+        with pytest.raises(NotImplementedError):
+            engine.topk(ctx, 4)
+        assert not engine.kernel_degraded
+        return
+    vals, idx = engine.topk(ctx, 4)
+    assert engine.kernel_degraded
+    monkeypatch.setattr(rt, "kernel_score", good)
+    rv, ri = rt.topk(params, engine.cache, jax.numpy.asarray(ctx),
+                     jax.numpy.ones(ctx.shape, rt.wdtype), K=4)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(rv))
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ri))
+
+
 # ---------------------------------------------------------------------------
 # deadline clock skew
 # ---------------------------------------------------------------------------

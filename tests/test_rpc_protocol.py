@@ -27,7 +27,7 @@ import pytest
 
 import jax
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.fields import uniform_layout
 from repro.data.synthetic_ctr import SyntheticCTR
 from repro.models.recsys import fwfm

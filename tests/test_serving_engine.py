@@ -147,6 +147,48 @@ def test_corpus_score_kernel_topk_vs_argsort(rng, n, block_n, K):
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_i))
 
 
+def _merge_case(rng, n, rho=3, k=8, Bq=3, dup=0):
+    """Kernel inputs with the first ``dup`` rows of Q/a_I repeated through
+    the slab (exact score ties across tiles)."""
+    Q = rng.standard_normal((n, rho, k), dtype=np.float32)
+    a_I = rng.standard_normal(n).astype(np.float32)
+    if dup:
+        Q = Q[np.arange(n) % dup]
+        a_I = a_I[np.arange(n) % dup]
+    e = rng.standard_normal(rho).astype(np.float32)
+    PC = rng.standard_normal((Bq, rho, k), dtype=np.float32)
+    a_C = rng.standard_normal(Bq).astype(np.float32)
+    return [jnp.asarray(x) for x in (Q, a_I, e, PC, a_C)]
+
+
+@pytest.mark.parametrize("case", ["ties", "K=block_n+1", "K=live"])
+def test_corpus_topk_merge_matches_lax_top_k(rng, case):
+    """The in-kernel selection merge keeps ``lax.top_k``'s contract over
+    the slab: rows best-first, equal scores to the LOWEST slot, dead slots
+    never ahead of a live one — checked against ``lax.top_k`` of the
+    kernel's own full-mode scores (same per-item arithmetic, so exact)."""
+    block_n = 8
+    n, K, valid = 64, 12, None
+    if case == "ties":                  # 5 distinct rows repeated 13 times
+        args = _merge_case(rng, n, dup=5)
+        valid = jnp.asarray(np.arange(n) % 7 != 3)
+    elif case == "K=block_n+1":
+        args = _merge_case(rng, n)
+        K = block_n + 1
+    else:                               # every live slot, none dead shown
+        args = _merge_case(rng, n)
+        live = rng.random(n) < 0.3
+        valid = jnp.asarray(live)
+        K = int(live.sum())
+    full = ops.dplr_corpus_score(*args, valid, block_n=block_n)
+    want_v, want_i = jax.lax.top_k(full, K)
+    vals, idx = ops.dplr_corpus_score(*args, valid, topk=K, block_n=block_n)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(want_v))
+    if valid is not None:
+        assert np.asarray(valid)[np.asarray(idx)].all()
+
+
 def test_corpus_kernel_consistent_with_algorithm1(rng):
     """Corpus kernel == rk.dplr_score_items on a real DPLR parameterization
     (pairwise term only: a_I = 0.5 t_I, a_C = 0.5 s_C)."""

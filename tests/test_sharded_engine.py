@@ -22,6 +22,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.fields import uniform_layout
 from repro.data.synthetic_ctr import SyntheticCTR
@@ -114,6 +116,22 @@ def test_sharded_churn_parity_and_identical_slot_assignment(use_pallas):
     wv, wi = ref.topk(q["context_ids"], K, q["context_weights"])
     np.testing.assert_array_equal(np.asarray(gv), np.asarray(wv))
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
+
+
+def test_model_placed_on_every_shard_at_refresh():
+    """``refresh`` replicates the model onto the mesh once, so a dispatch
+    whose context is already on the mesh moves no array between devices
+    (left on one device, the whole model would be copied to every shard
+    on every call)."""
+    _, cfg, params, data, q = _setup(n=20)
+    sh, _ = _pair(cfg, params, q, capacity=32)
+    replicated = NamedSharding(sh.mesh, P())
+    ctx, w = jax.device_put((q["context_ids"], q["context_weights"]),
+                            replicated)
+    jax.block_until_ready(sh.topk(ctx, 5, w))               # trace + compile
+    with jax.transfer_guard_device_to_device("disallow"):
+        jax.block_until_ready(sh.topk(ctx, 5, w))
+        jax.block_until_ready(sh.score(ctx, w))
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Substrate tests: embedding bag, sharded lookup, optimizers, schedules,
 gradient accumulation, int8 compression, data pipeline."""
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp
