@@ -649,26 +649,22 @@ def _kernel_dispatch(states, key, launch):
     hiding behind the jnp path."""
     rt = states[0].runtime
     key = key + (tuple(st.capacity for st in states),)
+    warm = True                 # an injected fault degrades unconditionally
     try:
         for st in states:
             if st._injector is not None:
                 st._injector.check("kernel")
-    except Exception:                     # noqa: BLE001 — injected fault
-        warm = True
-    else:
         warm = key in rt.kernel_warm
-        try:
-            with scoring_guard():
-                out = launch()
-        except Exception:                 # noqa: BLE001 — see docstring
-            if not warm:
-                raise
-        else:
-            rt.kernel_warm.add(key)
-            return out
-    for st in states:
-        st.kernel_degraded = True
-    return None
+        with scoring_guard():
+            out = launch()
+    except Exception:                     # noqa: BLE001 — see docstring
+        if not warm:
+            raise
+        for st in states:
+            st.kernel_degraded = True
+        return None
+    rt.kernel_warm.add(key)
+    return out
 
 
 def fused_topk(states, context_ids, K: int, context_weights=None):
