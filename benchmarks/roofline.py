@@ -173,16 +173,16 @@ def analyze_record(rec: dict, peaks: dict) -> dict:
     }
 
 
-def load_all(peaks: dict, mesh: str = "single",
-             include_tagged: bool = False):
-    rows = []
+def load_records(mesh: str = "single", include_tagged: bool = False):
+    """The successful dry-run records of one mesh."""
+    recs = []
     for f in sorted(glob.glob(os.path.join(RESULTS_DIR, f"*__{mesh}.json"))):
         if "+" in os.path.basename(f) and not include_tagged:
             continue   # optimized §Perf variants live in their own table
         rec = json.load(open(f))
         if rec.get("ok"):
-            rows.append(analyze_record(rec, peaks))
-    return rows
+            recs.append(rec)
+    return recs
 
 
 def render_markdown(rows) -> str:
@@ -199,16 +199,19 @@ def render_markdown(rows) -> str:
 
 
 def main(quick: bool = False, hw: str | None = None):
+    recs = load_records("single")
+    if not recs:
+        print("roofline: no dry-run records found — run "
+              "`python -m repro.launch.dryrun` first")
+        return []
+    # only records need peaks: a process with no records (the CPU smoke
+    # run) never asks which chip it is on
     peaks = hw_profile(hw)
     print(f"roofline: peaks of {hw or 'this device'} "
           f"(peak {peaks['peak_flops']/1e12:.0f} TFLOP/s, HBM "
           f"{peaks['hbm_bw']/1e9:.0f} GB/s, ICI {peaks['ici_bw']/1e9:.0f} "
           f"GB/s)")
-    rows = load_all(peaks, "single")
-    if not rows:
-        print("roofline: no dry-run records found — run "
-              "`python -m repro.launch.dryrun` first")
-        return []
+    rows = [analyze_record(rec, peaks) for rec in recs]
     print("roofline: arch | shape | compute_s | memory_s | coll_s | "
           "bottleneck | frac | useful")
     for r in rows:

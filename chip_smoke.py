@@ -16,13 +16,16 @@ One chip, in phases that each fail the script on error:
   2. build  — full-width params, a corpus of 8192 items (fig1's largest
      auction) in a slab of capacity 8192;
   3. warmup — the frontend's (Bq x K) grid plus full-mode scores at
-     Bq = 1 and 16, with the compilation-cache file count before and
-     after; then requests with K in 1..16 in bursts of mixed size;
+     Bq = 1 and 16, with the compilation-cache entries before and after
+     and the cache's hits and misses; then requests with K in
+     1..16 in bursts of mixed size;
   4. churn + refresh — remove, add and update a few items, install a new
      model snapshot, serve again;
   5. asserts — ``kernel_degraded`` is False, ``trace_count`` did not move
-     after warmup, every returned slot is live, and sampled top-Ks agree
-     with a float64 NumPy oracle (``topk_agrees``).
+     after warmup, every returned slot is live, every warmup compile
+     either hit the compilation cache or was written to it, and sampled
+     top-Ks agree (``topk_agrees``) with a float64 NumPy oracle computed
+     from the weights and the ids in each slot (``Oracle``).
 ``--chips 4`` runs only the sharded slab (capacity 32768, four shards)
 behind the frontend, each reply checked against a single-device engine
 over the same corpus and against the oracle.  Request latencies printed
@@ -69,36 +72,84 @@ def _hbm_bytes() -> int | None:
     return stats.get("bytes_in_use")
 
 
-def oracle(engine, ctx_ids):
-    """float64 scores of every slab slot for each context row, with the
-    per-slot tolerance: f32 epsilon x the reduction length (rho*k squared
-    terms plus the addends) x the sum of the absolute values of the terms.
-    Inputs are the engine's item cache and its context cache, computed at
-    matmul precision "highest"; dead slots score -inf."""
-    import jax
-    import jax.numpy as jnp
+class Oracle:
+    """float64 NumPy scores of every slab slot, from the model's weights
+    and the ids this script put in each slot — apart from the program's
+    item rows, churn writes, context cache and scorer.  Embeddings and
+    linear weights are gathered on the host from one copy of the arena
+    (arena row = the field's offset + the local id; every weight is 1);
+    the model is FwFM with R = U^T diag(e) U - diag(U^T diag(e) U):
 
-    rt = engine.runtime
-    with jax.default_matmul_precision("highest"):
-        P_C, s_C, lin_C = rt._context_impl(
-            engine.params, jnp.asarray(ctx_ids),
-            jnp.ones(ctx_ids.shape, rt.wdtype))
-    f64 = lambda a: np.asarray(a, np.float64)          # noqa: E731
-    c = engine.cache
-    Q, t, lin, valid = f64(c.Q_I), f64(c.t_I), f64(c.lin_I), np.asarray(
-        c.valid)
-    e, bias = f64(engine.params["e"]), float(engine.params["bias"])
-    P_C, s_C, lin_C = f64(P_C), f64(s_C), f64(lin_C)
-    sq = ((P_C[:, None] + Q[None]) ** 2).sum(-1)         # (Bq, n, rho)
-    pair = sq @ e
-    score = bias + lin_C[:, None] + lin[None] + 0.5 * (
-        s_C[:, None] + t[None] + pair)
-    absum = (abs(bias) + np.abs(lin_C)[:, None] + np.abs(lin)[None]
-             + 0.5 * (np.abs(s_C)[:, None] + np.abs(t)[None]
-                      + sq @ np.abs(e)))
-    rho, k = Q.shape[1:]
-    tol = EPS32 * (rho * k + 8) * absum
-    return np.where(valid[None], score, -np.inf), tol
+        score = bias + lin_C + lin_I
+                + 0.5 (sum_f d_f |v_f|^2 + sum_r e_r |sum_f U_rf v_f|^2)
+
+    Per slot the tolerance is f32 epsilon x the longest reduction (the
+    context projection over m_C fields, the item projection over m_I,
+    rho*k squared terms, and the addends) x the same expression over the
+    absolute values of every term.  Dead slots score -inf."""
+
+    def __init__(self, cfg, params, item_ids):
+        self.layout = cfg.layout
+        self.ids = np.array(item_ids)
+        self.live = np.ones(len(self.ids), bool)
+        self.set_params(params)
+
+    def set_params(self, params) -> None:
+        f64 = lambda a: np.asarray(a, np.float64)          # noqa: E731
+        self.emb = np.asarray(params["embedding"])
+        self.lin = np.asarray(params["linear"]).reshape(-1)
+        self.U, self.e = f64(params["U"]), f64(params["e"])
+        self.bias = float(params["bias"])
+        self.d = -np.einsum("r,rm,rm->m", self.e, self.U, self.U)
+        self._items = None
+
+    def write(self, slots, ids) -> None:
+        """``ids`` now sit in ``slots``, live."""
+        self.ids[slots] = ids
+        self.live[slots] = True
+        self._items = None
+
+    def remove(self, slots) -> None:
+        self.live[slots] = False
+
+    def _side(self, kind: str, ids):
+        """float64 (P, |P|, s, |s|, lin) of one side's rows: the rank-space
+        projection sum_f U_rf v_f, its d-term sum_f d_f |v_f|^2, the
+        first-order term, with the absolute-value bounds."""
+        lay = self.layout
+        fields = (lay.context_field_idx if kind == "context"
+                  else lay.item_field_idx)
+        col = {int(f): j for j, f in enumerate(fields)}
+        V = np.zeros((len(ids), len(fields), self.emb.shape[1]))
+        lin = np.zeros(len(ids))
+        for j, slot in enumerate(lay.slots_of(kind)):
+            f = int(lay.slot_to_field[slot])
+            rows = ids[:, j].astype(np.int64) + int(lay.field_offsets[f])
+            V[:, col[f]] += self.emb[rows]
+            lin += self.lin[rows]
+        U, d = self.U[:, fields], self.d[fields]
+        sq = (V * V).sum(-1)
+        return (np.einsum("rm,nmk->nrk", U, V),
+                np.einsum("rm,nmk->nrk", np.abs(U), np.abs(V)),
+                sq @ d, sq @ np.abs(d), lin)
+
+    def scores(self, ctx_ids):
+        """((Bq, slots) scores, (Bq, slots) tolerances) for contexts."""
+        if self._items is None:
+            self._items = self._side("item", self.ids)
+        QI, QIa, tI, tIa, linI = self._items
+        PC, PCa, sC, sCa, linC = self._side("context", np.asarray(ctx_ids))
+        e = self.e
+        pair = ((PC[:, None] + QI[None]) ** 2).sum(-1) @ e
+        pair_abs = ((PCa[:, None] + QIa[None]) ** 2).sum(-1) @ np.abs(e)
+        score = self.bias + linC[:, None] + linI[None] + 0.5 * (
+            sC[:, None] + tI[None] + pair)
+        absum = (abs(self.bias) + np.abs(linC)[:, None] + np.abs(linI)[None]
+                 + 0.5 * (sCa[:, None] + tIa[None] + pair_abs))
+        rho, k = QI.shape[1:]
+        length = (self.layout.n_context + self.layout.n_item + rho * k + 8)
+        tol = EPS32 * length * absum
+        return np.where(self.live[None], score, -np.inf), tol
 
 
 def topk_agrees(vals, idx, score, tol) -> str | None:
@@ -139,13 +190,13 @@ def _serve(fe, ctxs, ks, bursts):
     return out
 
 
-def _check(engine, ctxs, served, sample) -> int:
+def _check(engine, ref, ctxs, served, sample) -> int:
     """Liveness of every reply, oracle agreement of ``sample`` of them."""
     for p, _ in served:
         _, slots = p.result()
         if not engine.is_live(slots).all():
             raise AssertionError(f"reply surfaced a dead slot: {slots}")
-    score, tol = oracle(engine, ctxs[sample])
+    score, tol = ref.scores(ctxs[sample])
     for row, j in enumerate(sample):
         vals, slots = served[j][0].result()
         why = topk_agrees(np.asarray(vals, np.float64), np.asarray(slots),
@@ -155,45 +206,51 @@ def _check(engine, ctxs, served, sample) -> int:
     return len(sample)
 
 
-def one_chip(cfg, seed: int, n_items: int, cache_dir: str | None,
+def one_chip(cfg, seed: int, n_items: int, cache_dir: str,
              n_requests: int = 32) -> None:
     import jax
     import jax.numpy as jnp
 
-    from repro.launch.compile_cache import cache_entries
+    from repro.launch.compile_cache import cache_counts, cache_entries
     from repro.models.recsys import fwfm
     from repro.serving import CorpusRankingEngine, QueryFrontend
 
     rng = np.random.default_rng(seed)
     t0 = time.perf_counter()
     params = fwfm.init(jax.random.PRNGKey(seed), cfg)
-    engine = CorpusRankingEngine(cfg, _ids(cfg.layout, "item", n_items, rng),
-                                 capacity=n_items, use_pallas_kernel=True)
+    items = _ids(cfg.layout, "item", n_items, rng)
+    engine = CorpusRankingEngine(cfg, items, capacity=n_items,
+                                 use_pallas_kernel=True)
     engine.refresh(params, step=0)
     jax.block_until_ready(engine.cache)
     print(f"build: {time.perf_counter() - t0:.3f} s, {n_items} items, "
           f"capacity {engine.capacity}, hbm bytes_in_use={_hbm_bytes()}")
+    ref = Oracle(cfg, params, items)
 
     ctxs = _ids(cfg.layout, "context", 2 * n_requests, rng)
     fe = QueryFrontend(engine, max_batch=MAX_BATCH, max_k=MAX_K,
                        auto_pump=False)
-    before = cache_entries(cache_dir) if cache_dir else 0
+    before, (hits0, misses0) = cache_entries(cache_dir), cache_counts()
     t0 = time.perf_counter()
     dispatches = fe.warmup(ctxs[0])
     for bq in (1, MAX_BATCH):
         jax.block_until_ready(engine.score(ctxs[:bq]))
     warm_s = time.perf_counter() - t0
-    after = cache_entries(cache_dir) if cache_dir else 0
+    after, (hits, misses) = cache_entries(cache_dir), cache_counts()
+    hits, misses = hits - hits0, misses - misses0
+    written = len(after - before)
     traced = engine.trace_count
     print(f"warmup: {dispatches} grid dispatches + full-mode Bq=1,16 in "
           f"{warm_s:.3f} s, {traced} traces, compilation cache {cache_dir}: "
-          f"{before} -> {after} files")
+          f"{len(before)} -> {len(after)} entries ({written} written), "
+          f"{hits} hits, {misses} misses")
 
     ks = rng.integers(1, MAX_K + 1, 2 * n_requests)
     bursts = [1, 3, 16, 5, 2, 4, 1]                  # 32 requests
     served = _serve(fe, ctxs, ks, bursts)
     lat = [t for _, t in served]
-    checked = _check(engine, ctxs, served, list(range(0, n_requests, 3)))
+    checked = _check(engine, ref, ctxs, served,
+                     list(range(0, n_requests, 3)))
     print(f"serve: {len(served)} requests in bursts {bursts}, "
           f"{fe.stats['dispatches']} dispatches; smoke reading (not a "
           f"benchmark): median request latency {np.median(lat) * 1e3:.3f} "
@@ -202,17 +259,25 @@ def one_chip(cfg, seed: int, n_items: int, cache_dir: str | None,
     live = engine.valid_slots
     gone = live[rng.choice(len(live), 4, replace=False)]
     engine.remove_items(gone)
-    added = engine.add_items(_ids(cfg.layout, "item", 4, rng))
+    ref.remove(gone)
+    new = _ids(cfg.layout, "item", 4, rng)
+    added = engine.add_items(new)
+    _require(added.max() < len(ref.live) and not ref.live[added].any(),
+             f"add_items returned slots {added} that were not free")
+    ref.write(added, new)
     upd = np.setdiff1d(live, gone)[:3]
-    engine.update_items(upd, _ids(cfg.layout, "item", 3, rng))
+    new = _ids(cfg.layout, "item", 3, rng)
+    engine.update_items(upd, new)
+    ref.write(upd, new)
     bumped = dict(params)
     bumped["bias"] = params["bias"] + jnp.asarray(0.5, params["bias"].dtype)
     t0 = time.perf_counter()
     engine.refresh(bumped, step=1)
     jax.block_until_ready(engine.cache)
     refresh_s = time.perf_counter() - t0
+    ref.set_params(bumped)
     served2 = _serve(fe, ctxs[n_requests:], ks[n_requests:], [8, 1, 7])
-    checked2 = _check(engine, ctxs[n_requests:], served2,
+    checked2 = _check(engine, ref, ctxs[n_requests:], served2,
                       list(range(len(served2))))
     print(f"churn: removed {gone.tolist()}, added into {added.tolist()}, "
           f"updated {upd.tolist()}; refresh {refresh_s:.3f} s; "
@@ -221,9 +286,13 @@ def one_chip(cfg, seed: int, n_items: int, cache_dir: str | None,
     _require(not engine.kernel_degraded, "kernel path degraded to jnp")
     _require(engine.trace_count == traced,
              f"retraced after warmup: {traced} -> {engine.trace_count}")
-    _require(cache_dir is None or after > 0, "compilation cache stayed empty")
+    _require(hits + misses > 0, "warmup never consulted the compilation "
+             "cache")
+    _require(written >= misses, f"{misses} warmup compiles missed the "
+             f"cache but only {written} entries were written")
     print(f"asserts: kernel_degraded=False, retraces after warmup=0, "
-          f"all returned slots live, compilation cache files={after}")
+          f"all returned slots live, every warmup compile hit the "
+          f"compilation cache ({hits}) or was written to it ({misses})")
 
 
 def four_chips(cfg, seed: int, capacity: int, n_requests: int = 24) -> None:
@@ -248,6 +317,7 @@ def four_chips(cfg, seed: int, capacity: int, n_requests: int = 24) -> None:
     jax.block_until_ready((sharded.cache, single.cache))
     print(f"build: {time.perf_counter() - t0:.3f} s, capacity {capacity} "
           f"in {sharded.n_shards} shards of {sharded.local_capacity}")
+    ref = Oracle(cfg, params, items)
 
     ctxs = _ids(cfg.layout, "context", n_requests, rng)
     fe = QueryFrontend(sharded, max_batch=MAX_BATCH, max_k=MAX_K,
@@ -266,7 +336,7 @@ def four_chips(cfg, seed: int, capacity: int, n_requests: int = 24) -> None:
         rv, ri = (np.asarray(a)[0, :ks[j]] for a in single.topk(
             ctxs[j:j + 1], next_pow2(int(ks[j]))))
         exact += int(np.array_equal(si, ri) and np.array_equal(sv, rv))
-        score, tol = oracle(single, ctxs[j:j + 1])
+        score, tol = ref.scores(ctxs[j:j + 1])
         for name, v, i in (("sharded", sv, si), ("single", rv, ri)):
             why = topk_agrees(np.asarray(v, np.float64), np.asarray(i),
                               score[0], tol[0])

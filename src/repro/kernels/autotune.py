@@ -213,13 +213,12 @@ def tune_corpus_score(n: int, rho: int, k: int, Bq: int, K: int, *,
         sweep_bf16_acc = jnp.dtype(dtype) == jnp.bfloat16
     accs = ("float32", "bfloat16") if sweep_bf16_acc else ("float32",)
 
-    default_bn = blocks.CORPUS_TILE_N
-    if ops._on_tpu() if interpret is None else not interpret:
-        # compiled by Mosaic: a tile over the scoped-VMEM rule would not
-        # compile, so the sweep stays inside it
-        default_bn = blocks.corpus_vmem_tile(default_bn, Bq)
-        candidates = [bn for bn in candidates
-                      if bn == blocks.corpus_vmem_tile(bn, Bq)]
+    # the sweep stays inside the scoped-VMEM rule on every backend: a
+    # block_n=None call caps its tile by that rule (ops._resolve_tile),
+    # so a larger winner would be registered and never run
+    default_bn = blocks.corpus_vmem_tile(blocks.CORPUS_TILE_N, Bq)
+    candidates = [bn for bn in candidates
+                  if bn == blocks.corpus_vmem_tile(bn, Bq)]
     sweep = dict.fromkeys(candidates)       # ordered, deduped
     sweep[default_bn] = None                # the default always competes
     results: list[CandidateResult] = []

@@ -92,3 +92,33 @@ def test_score_items_compiles_for_v5e(one_chip, no_compile_cache):
             V, U, e, d, P, s, interpret=False),
         one_chip, ((N, M_I, K_EMB), f32), ((RHO, M_I), f32), ((RHO,), f32),
         ((M_I,), f32), ((RHO, K_EMB), f32), ((), f32))
+
+
+def test_kernel_locations_relative_to_checkout(one_chip, no_compile_cache):
+    """A cache key must not depend on where the checkout lies.  The
+    Mosaic kernel rides inside the program as serialized IR whose source
+    locations JAX does not strip before hashing; with the prefix the
+    compile-cache helper sets, they name files relative to the checkout."""
+    import base64
+    import re
+
+    from repro.launch import compile_cache
+
+    name = "jax_hlo_source_file_canonicalization_regex"
+    prev = getattr(jax.config, name)
+    jax.config.update(name, compile_cache.SOURCE_PREFIX)
+    try:
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in _corpus_shapes(4)]
+        text = jax.jit(lambda Q, a, e, P, aC, v: ops.dplr_corpus_score(
+            Q, a, e, P, aC, v, topk=TOPK, interpret=False)).lower(
+                *args).as_text()
+    finally:
+        jax.config.update(name, prev)
+    # the kernel body is base64 MLIR bytecode, whose magic is "ML\xefR"
+    bodies = [base64.b64decode(b) for b in re.findall(
+        r"TUzvUg[A-Za-z0-9+/=]+", text)]
+    assert bodies, "no serialized Mosaic kernel in the program"
+    for body in bodies:
+        assert compile_cache.CHECKOUT.encode() not in body
+        assert b"src/repro/kernels/dplr_corpus_score.py" in body
