@@ -242,6 +242,7 @@ def dplr_corpus_score(
             out_specs=blocks.col_tiles(Bq, block_n),
             out_shape=jax.ShapeDtypeStruct((Bq, n_pad), jnp.float32),
             interpret=interpret,
+            name="dplr_corpus_score_full",
         )(*args)[:, :n]
 
     if not 0 < topk <= n:
@@ -266,6 +267,7 @@ def dplr_corpus_score(
             jax.ShapeDtypeStruct((Bq, topk), jnp.int32),
         ],
         interpret=interpret,
+        name="dplr_corpus_score_topk",
     )(*args)
 
 
@@ -429,5 +431,6 @@ def dplr_corpus_score_multi(
             jax.ShapeDtypeStruct((SB, topk), jnp.int32),
         ],
         interpret=interpret,
+        name="dplr_corpus_score_multi_topk",
     )(*args)
     return vals.reshape(S, Bq, topk), idx.reshape(S, Bq, topk)

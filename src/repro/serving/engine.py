@@ -92,6 +92,7 @@ from repro.serving.corpus import ItemCorpusCache, next_pow2
 from repro.serving.errors import NotReady, RefreshFailed
 from repro.serving.runtime import ScorerRuntime
 from repro.serving.sanitize import scoring_guard
+from repro.serving.telemetry import span
 
 
 class CorpusState:
@@ -349,20 +350,23 @@ class CorpusState:
         complete); runs the writer barrier first (see ``_begin_write``)."""
         self._require_ready()
         self._begin_write()
-        ids, w = self._payload(ids, weights, "add_items")
-        dn = ids.shape[0]
-        if dn > self._n_free:
-            self._grow(dn - self._n_free)
-        slots = np.asarray([self._alloc_slot() for _ in range(dn)], np.int32)
-        try:
-            self._scatter_rows(slots, ids, w)
-        except Exception:
-            # roll the allocation back: the rows were never written, so
-            # n_items must not count them and the slots must stay free —
-            # the failed add is invisible (retryable) to every reader
-            for g in slots:
-                self._free_slot(int(g))
-            raise
+        with span("engine.write", op="add"):
+            ids, w = self._payload(ids, weights, "add_items")
+            dn = ids.shape[0]
+            if dn > self._n_free:
+                self._grow(dn - self._n_free)
+            slots = np.asarray([self._alloc_slot() for _ in range(dn)],
+                               np.int32)
+            try:
+                self._scatter_rows(slots, ids, w)
+            except Exception:
+                # roll the allocation back: the rows were never written,
+                # so n_items must not count them and the slots must stay
+                # free — the failed add is invisible (retryable) to every
+                # reader
+                for g in slots:
+                    self._free_slot(int(g))
+                raise
         return slots
 
     def update_items(self, indices, ids, weights=None) -> None:
@@ -370,27 +374,30 @@ class CorpusState:
         shape as ``add_items``); slot assignments are unchanged."""
         self._require_ready()
         self._begin_write()
-        slots = np.asarray(indices, np.int32).reshape(-1)
-        self._check_live(slots, "update_items")
-        ids, w = self._payload(ids, weights, "update_items",
-                               n_expected=slots.size)
-        self._scatter_rows(slots, ids, w)
+        with span("engine.write", op="update"):
+            slots = np.asarray(indices, np.int32).reshape(-1)
+            self._check_live(slots, "update_items")
+            ids, w = self._payload(ids, weights, "update_items",
+                                   n_expected=slots.size)
+            self._scatter_rows(slots, ids, w)
 
     def remove_items(self, indices) -> None:
         """Invalidate the given live slots (their rows become free; masked
         scoring pins them to -inf immediately).  One scatter dispatch."""
         self._require_ready()
         self._begin_write()
-        slots = np.asarray(indices, np.int32).reshape(-1)
-        self._check_live(slots, "remove_items")
-        # device-first, like _scatter_rows: a failed drop leaves the host
-        # mask/free-lists untouched (the remove simply didn't happen)
-        if self._injector is not None:
-            self._injector.check("write")
-        self.cache = self.runtime.drop_rows(self.cache, slots)
-        self._valid_np[slots] = False
-        for s in slots:
-            self._free_slot(int(s))
+        with span("engine.write", op="remove"):
+            slots = np.asarray(indices, np.int32).reshape(-1)
+            self._check_live(slots, "remove_items")
+            # device-first, like _scatter_rows: a failed drop leaves the
+            # host mask/free-lists untouched (the remove simply didn't
+            # happen)
+            if self._injector is not None:
+                self._injector.check("write")
+            self.cache = self.runtime.drop_rows(self.cache, slots)
+            self._valid_np[slots] = False
+            for s in slots:
+                self._free_slot(int(s))
 
     def _check_live(self, slots, op):
         if len(np.unique(slots)) != len(slots):
@@ -457,24 +464,26 @@ class CorpusState:
         capacity/D rows (the global-order host slab reshapes to the
         physical (local, D) view for free, because ownership is striped)."""
         self._begin_write()
-        if self.mesh is not None:
-            # one replica per shard, placed once per snapshot: left on one
-            # device, the whole model (embedding arena included) would be
-            # copied to every shard on every sharded dispatch
-            params = jax.device_put(params, NamedSharding(self.mesh, P()))
-        self.params = params
-        if self.mesh is None:
-            self.cache = self.runtime.build(
-                params, jnp.asarray(self._slab_ids),
-                jnp.asarray(self._slab_w, self._wdtype),
-                jnp.asarray(self._valid_np))
-        else:
-            lc = self.local_capacity
-            ids = self._slab_ids.reshape(lc, self._D, -1)
-            w = self._slab_w.reshape(lc, self._D, -1)
-            self.cache = self.runtime.build(
-                params, jnp.asarray(ids), jnp.asarray(w, self._wdtype),
-                jnp.asarray(self._valid_np.reshape(lc, self._D)))
+        with span("engine.refresh", op="refresh"):
+            if self.mesh is not None:
+                # one replica per shard, placed once per snapshot: left on
+                # one device, the whole model (embedding arena included)
+                # would be copied to every shard on every sharded dispatch
+                params = jax.device_put(params,
+                                        NamedSharding(self.mesh, P()))
+            self.params = params
+            if self.mesh is None:
+                self.cache = self.runtime.build(
+                    params, jnp.asarray(self._slab_ids),
+                    jnp.asarray(self._slab_w, self._wdtype),
+                    jnp.asarray(self._valid_np))
+            else:
+                lc = self.local_capacity
+                ids = self._slab_ids.reshape(lc, self._D, -1)
+                w = self._slab_w.reshape(lc, self._D, -1)
+                self.cache = self.runtime.build(
+                    params, jnp.asarray(ids), jnp.asarray(w, self._wdtype),
+                    jnp.asarray(self._valid_np.reshape(lc, self._D)))
         self.model_step = step
         self.refresh_count += 1
         self.last_refresh_time = time.monotonic()

@@ -213,6 +213,18 @@ chaos suite injects (docs/robustness.md):
     shuts down gracefully (in-flight batches resolve to real results,
     queued requests fail with typed ``Unservable``).
 
+Telemetry
+---------
+``frontend.telemetry`` (a ``repro.serving.telemetry.Stages``) holds the
+latency histograms of where requests wait: ``queue`` (submit -> the
+launch holding the request returns) and ``inflight`` (launch returns ->
+finish), observed at finish; ``read_block`` per resolved batch; and the
+``write.lock`` / ``write.barrier`` / ``write.apply`` stages of the writer
+wrappers.  An ``RpcServer`` adds ``rpc`` and ``server``.  The profiler
+spans ``frontend.dispatch``, ``frontend.resolve`` and
+``frontend.barrier`` mark the same boundaries in a trace;
+``health()["stages"]`` reports the histograms' quantiles.
+
 The frontend is an event-loop-style coalescer, not a thread pool: one
 thread calls ``submit``/``pump``/``result``; a separate churn thread is
 supported via the frontend's writer wrappers (above).  All public entry
@@ -234,6 +246,7 @@ from repro.serving.corpus import next_pow2
 from repro.serving.engine import fused_topk
 from repro.serving.errors import (Degraded, DeadlineExceeded, DispatchFailed,
                                   Overloaded, ServingError, Unservable)
+from repro.serving.telemetry import Stages, span
 
 
 class PendingQuery:
@@ -250,13 +263,20 @@ class PendingQuery:
     served K below the requested ``k`` (``pressure_k``); the reply is
     then the exact top-``served_k`` prefix of the full answer and
     ``degraded`` is True.  Healthy replies have ``served_k == k``.
+
+    Telemetry: ``batch`` is the frontend's dispatch sequence number of
+    the micro-batch that answered the request; ``t_submit_ns`` and
+    ``t_finish_ns`` are ``time.perf_counter_ns`` stamps of submit entry
+    and of the finish (stage boundaries of ``repro.serving.telemetry``).
     """
 
     __slots__ = ("k", "served_k", "degraded", "deadline", "submit_time",
-                 "done_time", "tenant", "_frontend", "_ctx", "_w",
+                 "done_time", "tenant", "batch", "t_submit_ns",
+                 "t_finish_ns", "_frontend", "_ctx", "_w",
                  "_scores", "_slots", "_error", "_taken")
 
-    def __init__(self, frontend, tenant, ctx, w, k, deadline, submit_time):
+    def __init__(self, frontend, tenant, ctx, w, k, deadline, submit_time,
+                 t_submit_ns):
         self.k = k
         self.served_k = k            # lowered only by the pressure clamp
         self.degraded = False
@@ -264,6 +284,9 @@ class PendingQuery:
         self.submit_time = submit_time
         self.done_time = None
         self.tenant = tenant
+        self.batch = None
+        self.t_submit_ns = t_submit_ns
+        self.t_finish_ns = None
         self._frontend = frontend
         self._ctx = ctx
         self._w = w
@@ -289,8 +312,11 @@ class PendingQuery:
             raise self._error
         return self._scores, self._slots
 
-    def _finish(self, scores, slots, now):
+    def _finish(self, scores, slots, now, batch, t_finish_ns):
         self._scores, self._slots = scores, slots
+        # stamped before done() turns True: a reader on another thread
+        # that sees done() sees them
+        self.batch, self.t_finish_ns = batch, t_finish_ns
         self.done_time = now
         self._frontend = self._ctx = self._w = None
 
@@ -313,13 +339,15 @@ class _InFlight:
     tenant's assembled rows, so the resolve-time recovery path can
     re-dispatch just this segment as a classic single-tenant batch
     (bit-exact: the fused kernel's per-segment rows equal the unpacked
-    dispatch)."""
+    dispatch).  ``batch`` is the dispatch sequence number and
+    ``t_launch_ns`` the ``perf_counter_ns`` stamp at which the launch
+    returned."""
 
     __slots__ = ("requests", "vals", "idx", "tenant", "ctx", "w", "k_pad",
-                 "launch", "seg")
+                 "batch", "t_launch_ns", "launch", "seg")
 
-    def __init__(self, requests, vals, idx, tenant, ctx, w, k_pad,
-                 launch=None, seg=None):
+    def __init__(self, requests, vals, idx, tenant, ctx, w, k_pad, batch,
+                 t_launch_ns, launch=None, seg=None):
         self.requests = requests
         self.vals = vals
         self.idx = idx
@@ -327,6 +355,8 @@ class _InFlight:
         self.ctx = ctx
         self.w = w
         self.k_pad = k_pad
+        self.batch = batch
+        self.t_launch_ns = t_launch_ns
         self.launch = launch
         self.seg = seg
 
@@ -544,6 +574,9 @@ class QueryFrontend:
         self._lanes: dict[str, _TenantLane] = {}
         self._seq = 0                # global FIFO tie-break for EDF
         self._svc = None             # EWMA batch service time (seconds)
+        self._batches = 0            # dispatch sequence number
+        self._barrier_ns = 0         # drain time inside the current write
+        self.telemetry = Stages()
         self._window: collections.deque[_InFlight] = collections.deque()
         self._lock = threading.RLock()
         # retry backoff waits on a Condition bound to the frontend lock:
@@ -673,6 +706,7 @@ class QueryFrontend:
         open, and ``Unservable`` after ``close()``.  With ``auto_pump``
         a full bucket dispatches at once.
         """
+        t_submit = time.perf_counter_ns()
         with self._lock:
             if self._closed:
                 raise Unservable("frontend is closed", tenant=tenant)
@@ -698,7 +732,7 @@ class QueryFrontend:
                     tenant=lane.name)
             self._admit(lane, deadline, now)
             req = PendingQuery(self, lane.name, ctx, w, int(k), deadline,
-                               now)
+                               now, t_submit)
             heapq.heappush(lane.heap,
                            (math.inf if deadline is None else deadline,
                             self._seq, req))
@@ -991,7 +1025,8 @@ class QueryFrontend:
         resolve THIS lane's in-flight batches (blocking).  The state
         calls it (via ``on_mutate``) before any corpus mutation or model
         refresh; other tenants' queues and windows are untouched."""
-        with self._lock:
+        with self._lock, span("frontend.barrier", tenant=name):
+            t0 = time.perf_counter_ns()
             self.stats["drains"] += 1
             lane = self._lanes[name]
             now = self.clock()
@@ -1008,6 +1043,7 @@ class QueryFrontend:
                 else:
                     keep.append(fl)
             self._window = keep
+            self._barrier_ns += time.perf_counter_ns() - t0
 
     # -- writer entry points (atomic barrier + mutation) --------------------
     #
@@ -1019,29 +1055,44 @@ class QueryFrontend:
     # in between drain and the mask update (which could deliver slots the
     # in-progress churn is about to kill).
 
+    def _write(self, tenant, apply):
+        """``apply(engine)`` on the tenant's state under the frontend
+        lock, observing the ``write.lock``, ``write.barrier`` and
+        ``write.apply`` stages (the barrier runs inside the engine's
+        writer, through ``on_mutate``; ``_drain_tenant`` adds its time
+        to ``_barrier_ns``)."""
+        t0 = time.perf_counter_ns()
+        with self._lock:
+            t1 = time.perf_counter_ns()
+            self._barrier_ns = 0
+            out = apply(self._lane(tenant).engine)
+            t2 = time.perf_counter_ns()
+            observe = self.telemetry.observe
+            observe("write.lock", (t1 - t0) * 1e-9)
+            observe("write.barrier", self._barrier_ns * 1e-9)
+            observe("write.apply", (t2 - t1 - self._barrier_ns) * 1e-9)
+        return out
+
     def add_items(self, ids, weights=None, *, tenant: str | None = None):
         """``engine.add_items`` on the tenant's state under the frontend
         lock (drain + write atomic vs concurrent submits); returns the
         new slot indices."""
-        with self._lock:
-            return self._lane(tenant).engine.add_items(ids, weights)
+        return self._write(tenant, lambda eng: eng.add_items(ids, weights))
 
     def remove_items(self, indices, *, tenant: str | None = None) -> None:
         """``engine.remove_items`` under the frontend lock."""
-        with self._lock:
-            self._lane(tenant).engine.remove_items(indices)
+        self._write(tenant, lambda eng: eng.remove_items(indices))
 
     def update_items(self, indices, ids, weights=None, *,
                      tenant: str | None = None) -> None:
         """``engine.update_items`` under the frontend lock."""
-        with self._lock:
-            self._lane(tenant).engine.update_items(indices, ids, weights)
+        self._write(tenant,
+                    lambda eng: eng.update_items(indices, ids, weights))
 
     def refresh(self, params, step=None, *,
                 tenant: str | None = None) -> None:
         """``engine.refresh`` (model hot-swap) under the frontend lock."""
-        with self._lock:
-            self._lane(tenant).engine.refresh(params, step=step)
+        self._write(tenant, lambda eng: eng.refresh(params, step=step))
 
     def maybe_refresh(self, manager, template, select=lambda t: t, *,
                       tenant: str | None = None) -> bool:
@@ -1132,13 +1183,18 @@ class QueryFrontend:
     def _dispatch_live(self, lane, live: list[PendingQuery],
                        now: float) -> None:
         bq = min(next_pow2(len(live)), self.max_batch)
-        ctx, w = self._assemble(live, bq)
         k_pad = self._k_dispatch(lane, live)
+        self._batches += 1
+        batch = self._batches
         try:
-            # async dispatch: engine.topk returns device arrays without
-            # blocking — the device scores while the host assembles the
-            # next micro-batch (the overlap this frontend exists for)
-            vals, idx = self._launch(lane, ctx, w, k_pad)
+            with span("frontend.dispatch", batch=batch, rows=len(live),
+                      k=k_pad):
+                ctx, w = self._assemble(live, bq)
+                # async dispatch: engine.topk returns device arrays
+                # without blocking — the device scores while the host
+                # assembles the next micro-batch (the overlap this
+                # frontend exists for)
+                vals, idx = self._launch(lane, ctx, w, k_pad)
         except DispatchFailed as e:
             for r in live:
                 self.stats["failed"] += 1
@@ -1146,12 +1202,13 @@ class QueryFrontend:
                 r._fail(e, now)
             self._breaker_failure(lane, now)
             return
+        t_launch = time.perf_counter_ns()
         self._breaker_success(lane)
         self.stats["dispatches"] += 1
         self.stats["dispatched_rows"] += bq
         self.stats["padded_rows"] += bq - len(live)
         self._window.append(_InFlight(live, vals, idx, lane.name,
-                                      ctx, w, k_pad))
+                                      ctx, w, k_pad, batch, t_launch))
         while len(self._window) > self.inflight:
             self._resolve_oldest()
 
@@ -1185,20 +1242,28 @@ class QueryFrontend:
             for lane, live in live_pairs:
                 self._dispatch_live(lane, live, now)
             return
-        rows = [self._assemble(live, bq) for _, live in live_pairs]
-        states = [lane.engine for lane, _ in live_pairs]
-        # pad the SEGMENT count to its power-of-two bucket (phantom
-        # segments repeat the last tenant's slab + rows and are simply
-        # never read back): the fused trace grid stays the fixed
-        # (S buckets x Bq buckets x K buckets) set warmup_packed warms
-        s_pad = next_pow2(len(live_pairs))
-        ctx = np.stack([c for c, _ in rows]
-                       + [rows[-1][0]] * (s_pad - len(rows)))
-        w = np.stack([wt for _, wt in rows]
-                     + [rows[-1][1]] * (s_pad - len(rows)))
-        states = tuple(states + [states[-1]] * (s_pad - len(states)))
+        self._batches += 1
+        batch = self._batches
         try:
-            launch = self._launch_group(live_pairs, states, ctx, w, k_pad)
+            with span("frontend.dispatch", batch=batch,
+                      rows=sum(len(live) for _, live in live_pairs),
+                      k=k_pad):
+                rows = [self._assemble(live, bq) for _, live in live_pairs]
+                states = [lane.engine for lane, _ in live_pairs]
+                # pad the SEGMENT count to its power-of-two bucket
+                # (phantom segments repeat the last tenant's slab + rows
+                # and are simply never read back): the fused trace grid
+                # stays the fixed (S buckets x Bq buckets x K buckets)
+                # set warmup_packed warms
+                s_pad = next_pow2(len(live_pairs))
+                ctx = np.stack([c for c, _ in rows]
+                               + [rows[-1][0]] * (s_pad - len(rows)))
+                w = np.stack([wt for _, wt in rows]
+                             + [rows[-1][1]] * (s_pad - len(rows)))
+                states = tuple(states
+                               + [states[-1]] * (s_pad - len(states)))
+                launch = self._launch_group(live_pairs, states, ctx, w,
+                                            k_pad)
         except DispatchFailed as e:
             for lane, live in live_pairs:
                 for r in live:
@@ -1207,6 +1272,7 @@ class QueryFrontend:
                     r._fail(e, now)
                 self._breaker_failure(lane, now)
             return
+        t_launch = time.perf_counter_ns()
         self.stats["fused_dispatches"] += 1
         self.stats["fused_segments"] += len(live_pairs)
         for seg, (lane, live) in enumerate(live_pairs):
@@ -1216,7 +1282,8 @@ class QueryFrontend:
             self.stats["padded_rows"] += bq - len(live)
             self._window.append(_InFlight(live, None, None, lane.name,
                                           rows[seg][0], rows[seg][1],
-                                          k_pad, launch=launch, seg=seg))
+                                          k_pad, batch, t_launch,
+                                          launch=launch, seg=seg))
         while len(self._window) > self.inflight:
             self._resolve_oldest()
 
@@ -1247,62 +1314,76 @@ class QueryFrontend:
     # -- resolution (the only blocking step) --------------------------------
 
     def _resolve(self, fl: _InFlight) -> None:
-        t_read = self.clock()
-        lane = self._lanes.get(fl.tenant)
-        try:
-            if self._injector is not None:
-                self._injector.check("resolve")
-            if fl.launch is not None:
-                # fused batch: the first member segment pays the one
-                # blocking read of the shared (S, Bq, K) launch; the
-                # rest slice the cached host arrays for free
-                all_vals, all_idx = fl.launch.read()
-                vals, idx = all_vals[fl.seg], all_idx[fl.seg]
-            else:
-                vals = np.asarray(fl.vals)  # blocks until device finishes
-                idx = np.asarray(fl.idx)
-        except Exception:               # noqa: BLE001 — deferred device
-            # failure surfaced at materialization: re-dispatch the SAME
-            # assembled batch (fl.ctx/fl.w/fl.k_pad — bit-exact) and read
-            # it synchronously; only exhausted retries fail the requests
-            now = self.clock()
+        with span("frontend.resolve", batch=fl.batch):
+            t_read = self.clock()
+            lane = self._lanes.get(fl.tenant)
             try:
-                if lane is None:
-                    raise DispatchFailed(
-                        f"tenant {fl.tenant!r} removed with batch in "
-                        f"flight", tenant=fl.tenant)
-                vals, idx = self._launch(lane, fl.ctx, fl.w, fl.k_pad)
-                vals = np.asarray(vals)
-                idx = np.asarray(idx)
-            except DispatchFailed as e:
-                for r in fl.requests:
-                    self.stats["failed"] += 1
+                if self._injector is not None:
+                    self._injector.check("resolve")
+                if fl.launch is not None:
+                    # fused batch: the first member segment pays the one
+                    # blocking read of the shared (S, Bq, K) launch; the
+                    # rest slice the cached host arrays for free
+                    all_vals, all_idx = fl.launch.read()
+                    vals, idx = all_vals[fl.seg], all_idx[fl.seg]
+                else:
+                    vals = np.asarray(fl.vals)  # blocks until device done
+                    idx = np.asarray(fl.idx)
+            except Exception:               # noqa: BLE001 — deferred device
+                # failure surfaced at materialization: re-dispatch the
+                # SAME assembled batch (fl.ctx/fl.w/fl.k_pad — bit-exact)
+                # and read it synchronously; only exhausted retries fail
+                # the requests
+                now = self.clock()
+                try:
+                    if lane is None:
+                        raise DispatchFailed(
+                            f"tenant {fl.tenant!r} removed with batch in "
+                            f"flight", tenant=fl.tenant)
+                    vals, idx = self._launch(lane, fl.ctx, fl.w, fl.k_pad)
+                    vals = np.asarray(vals)
+                    idx = np.asarray(idx)
+                except DispatchFailed as e:
+                    for r in fl.requests:
+                        self.stats["failed"] += 1
+                        if lane is not None:
+                            lane.stats["failed"] += 1
+                        r._fail(e, now)
                     if lane is not None:
-                        lane.stats["failed"] += 1
-                    r._fail(e, now)
+                        self._breaker_failure(lane, now)
+                    return
                 if lane is not None:
-                    self._breaker_failure(lane, now)
-                return
-            if lane is not None:
-                self._breaker_success(lane)
-        now = self.clock()
-        # Admission-control service-time sample: the time this read spent
-        # BLOCKED on the device, not wall time since dispatch — a batch
-        # that sat resolved in a lazy window for 100 ms did not take
-        # 100 ms of service.  Under light load samples are ~0 (device
-        # idle => any sane deadline is feasible); under overload the
-        # window evicts into genuinely-blocking reads and the EWMA tracks
-        # the real per-batch cost — exactly the regime shedding matters.
-        dt = now - t_read
-        self._svc = dt if self._svc is None else 0.3 * dt + 0.7 * self._svc
-        for row, r in enumerate(fl.requests):
-            # host-side truncation: top-k_pad is sorted best-first, so
-            # its first served_k entries ARE the top-served_k (bit-exact;
-            # served_k == k unless the pressure clamp lowered it)
-            r._finish(vals[row, :r.served_k], idx[row, :r.served_k], now)
-            self.stats["completed"] += 1
-            if lane is not None:
-                lane.stats["completed"] += 1
+                    self._breaker_success(lane)
+            now = self.clock()
+            # Admission-control service-time sample: the time this read
+            # spent BLOCKED on the device, not wall time since dispatch —
+            # a batch that sat resolved in a lazy window for 100 ms did
+            # not take 100 ms of service.  Under light load samples are
+            # ~0 (device idle => any sane deadline is feasible); under
+            # overload the window evicts into genuinely-blocking reads
+            # and the EWMA tracks the real per-batch cost — exactly the
+            # regime shedding matters.
+            dt = now - t_read
+            self._svc = (dt if self._svc is None
+                         else 0.3 * dt + 0.7 * self._svc)
+            t_finish = time.perf_counter_ns()
+            observe = self.telemetry.observe
+            observe("read_block", dt)
+            # one launch stamp per batch: every request's inflight is
+            # the same
+            observe("inflight", (t_finish - fl.t_launch_ns) * 1e-9,
+                    len(fl.requests))
+            for row, r in enumerate(fl.requests):
+                # host-side truncation: top-k_pad is sorted best-first,
+                # so its first served_k entries ARE the top-served_k
+                # (bit-exact; served_k == k unless the pressure clamp
+                # lowered it)
+                r._finish(vals[row, :r.served_k], idx[row, :r.served_k],
+                          now, fl.batch, t_finish)
+                observe("queue", (fl.t_launch_ns - r.t_submit_ns) * 1e-9)
+                self.stats["completed"] += 1
+                if lane is not None:
+                    lane.stats["completed"] += 1
 
     def resolve(self, max_batches: int | None = None) -> int:
         """Resolve up to ``max_batches`` of the OLDEST in-flight
@@ -1499,8 +1580,10 @@ class QueryFrontend:
         Top level: ``ready`` (accepting submits), ``closed``, ``degraded``
         (any lane breaker not closed, any engine on its fallback kernel,
         or a recorded refresh failure), ``queue_depth``,
-        ``inflight_depth``, ``pump`` (running / restarts), and
-        ``packing`` (fused-dispatch counters + mean group size).  Per
+        ``inflight_depth``, ``pump`` (running / restarts),
+        ``packing`` (fused-dispatch counters + mean group size), and
+        ``stages``: per stage of ``repro.serving.telemetry``, the count
+        and the p50 / p90 / p99 in seconds since the frontend started.  Per
         tenant: breaker state and consecutive-failure count, queue depth,
         live item count, model step, seconds since the last model
         refresh, the last refresh error (if any), and whether the engine
@@ -1554,6 +1637,7 @@ class QueryFrontend:
                         else 0.0,
                 },
                 "tenants": lanes,
+                "stages": self.telemetry.summary(),
             }
 
     def close(self) -> None:

@@ -79,15 +79,28 @@ Graceful drain: ``shutdown()`` — wired to SIGTERM/SIGINT by
 for the reply writers, then takes the frontend's existing ``close()``
 path.  ``serve_in_thread`` runs the whole server on a daemon thread for
 tests, benchmarks, and ``serve.py --rpc``.
+
+Telemetry: every request gets a server sequence number ``req`` (the
+wire ``request_id`` is the client's and repeats across connections).
+The spans ``rpc.decode``, ``rpc.submit``, ``rpc.sweep`` and
+``rpc.reply`` (``repro.serving.telemetry``) mark the server's side of a
+request in a profiler trace, and each answered request adds one
+observation of the ``rpc`` and ``server`` stages to the frontend's
+``telemetry``.  The loop thread only stamps the reply and queues it;
+the next tick observes it on the frontend thread, which keeps the loop
+— the thread that sets the median on the auction cells — as short as
+it was.
 """
 from __future__ import annotations
 
 import asyncio
+import collections
 import contextlib
 import signal
 import socket
 import struct
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
@@ -96,6 +109,7 @@ from repro.serving.errors import (Degraded, DeadlineExceeded,
                                   DispatchFailed, NotReady, Overloaded,
                                   RefreshFailed, ServingError, Unservable)
 from repro.serving.faults import InjectedFault
+from repro.serving.telemetry import span, tracing
 
 MAX_FRAME = 1 << 20          # largest accepted payload (1 MiB)
 OP_RANK = 0x01
@@ -425,6 +439,10 @@ class RpcServer:
         self._tick_task: asyncio.Task | None = None
         self._conns: set[_Conn] = set()
         self._waiters: dict = {}           # PendingQuery -> asyncio.Future
+        self._reqs = 0                     # request sequence number
+        # (t_frame, t_reply, PendingQuery) of replies written and not yet
+        # observed: the loop appends, the frontend thread pops
+        self._replied: collections.deque = collections.deque()
         self._running = False
         self._shutdown_started = False
         self._shutdown_done: asyncio.Event | None = None
@@ -517,9 +535,21 @@ class RpcServer:
         """One scheduler turn on the frontend thread: dispatch aged/full
         buckets, then materialize every dispatched batch so the sweep
         can answer its waiters."""
+        self._observe_replies()
         self.frontend.pump()
         if self.frontend.inflight_depth:
             self.frontend.resolve()
+
+    def _observe_replies(self) -> None:
+        """The ``rpc`` and ``server`` stages of the replies written since
+        the last call.  The stages share the request's stamps, so
+        ``rpc + queue + inflight == server`` exactly."""
+        observe = self.frontend.telemetry.observe
+        while self._replied:
+            t_frame, t_reply, p = self._replied.popleft()
+            observe("rpc", (p.t_submit_ns - t_frame
+                            + t_reply - p.t_finish_ns) * 1e-9)
+            observe("server", (t_reply - t_frame) * 1e-9)
 
     async def _tick_loop(self) -> None:
         while self._running:
@@ -535,11 +565,12 @@ class RpcServer:
     def _sweep(self) -> None:
         """Complete the asyncio future of every finished request (runs
         on the loop thread; the waiter map is loop-thread-only)."""
-        done = [p for p in self._waiters if p.done()]
-        for p in done:
-            fut = self._waiters.pop(p)
-            if not fut.done():
-                fut.set_result(None)
+        with span("rpc.sweep"):
+            done = [p for p in self._waiters if p.done()]
+            for p in done:
+                fut = self._waiters.pop(p)
+                if not fut.done():
+                    fut.set_result(None)
 
     # -- connection handling ----------------------------------------------
 
@@ -569,6 +600,7 @@ class RpcServer:
                 payload = await self._read_frame(reader)
                 if payload is None:
                     break                          # clean EOF
+                t_frame = time.perf_counter_ns()
                 # backpressure: no new frame is parsed while this
                 # connection already has max_inflight_per_conn requests
                 # unanswered — the kernel buffer fills, the client blocks
@@ -576,14 +608,14 @@ class RpcServer:
                 op = payload[0]
                 if op == OP_RANK:
                     task = self._loop.create_task(
-                        self._handle_rank(conn, payload))
+                        self._handle_rank(conn, payload, t_frame))
                     conn.tasks.add(task)
                     task.add_done_callback(conn.tasks.discard)
                 else:
                     self.stats["protocol_errors"] += 1
                     err = RpcProtocolError(f"unknown opcode {op:#x}")
-                    await self._send(conn, encode_error_reply(
-                        _peek_request_id(payload), err))
+                    await self._send(conn, encode_error_reply,
+                                     _peek_request_id(payload), err)
                     conn.sem.release()
         except RpcProtocolError:
             # framing is broken (bad length prefix): the stream can no
@@ -615,25 +647,35 @@ class RpcServer:
                 f"declared frame length {n} outside [1, {MAX_FRAME}]")
         return await reader.readexactly(n)
 
-    async def _handle_rank(self, conn: _Conn, payload: bytes) -> None:
+    async def _handle_rank(self, conn: _Conn, payload: bytes,
+                           t_frame: int) -> None:
         """One request end to end: decode, submit on the frontend
-        thread, await the sweep, write the (ok or typed-error) reply."""
+        thread, await the sweep, write the (ok or typed-error) reply.
+        ``t_frame`` is the ``perf_counter_ns`` stamp at which the frame
+        was fully read."""
         request_id = _peek_request_id(payload)
+        self._reqs += 1
+        req = self._reqs
         try:
             try:
-                rq = decode_rank_request(payload)
+                if tracing():
+                    with span("rpc.decode", req=req):
+                        rq = decode_rank_request(payload)
+                else:
+                    rq = decode_rank_request(payload)
             except RpcProtocolError as e:
                 self.stats["protocol_errors"] += 1
-                await self._send(conn,
-                                 encode_error_reply(request_id, e))
+                await self._send(conn, encode_error_reply, request_id, e,
+                                 req=req)
                 return
             request_id = rq.request_id
             self.stats["requests"] += 1
             try:
-                pending = await self._fe(self._submit_sync, rq)
+                pending = await self._fe(self._submit_sync, rq, req)
             except Exception as e:         # noqa: BLE001 — typed on wire
                 self.stats["errors"] += 1
-                await self._send(conn, encode_error_reply(request_id, e))
+                await self._send(conn, encode_error_reply, request_id, e,
+                                 req=req)
                 return
             fut = self._loop.create_future()
             self._waiters[pending] = fut
@@ -644,11 +686,14 @@ class RpcServer:
                 scores, slots = pending.result()
             except Exception as e:         # noqa: BLE001 — typed on wire
                 self.stats["errors"] += 1
-                await self._send(conn, encode_error_reply(request_id, e))
+                await self._send(conn, encode_error_reply, request_id, e,
+                                 req=req, batch=pending.batch)
                 return
-            await self._send(conn, encode_ok_reply(
-                request_id, scores, slots, pending.degraded))
+            t_reply = await self._send(conn, encode_ok_reply, request_id,
+                                       scores, slots, pending.degraded,
+                                       req=req, batch=pending.batch)
             self.stats["replies"] += 1
+            self._replied.append((t_frame, t_reply, pending))
         except (ConnectionError, OSError, ServingError):
             # the client died (or rpc_write fired) before its reply
             # could land: the REQUEST still resolved above — nothing is
@@ -658,21 +703,36 @@ class RpcServer:
         finally:
             conn.sem.release()
 
-    def _submit_sync(self, rq: RankRequest):
+    def _submit_sync(self, rq: RankRequest, req: int):
         """Frontend-thread submit: the relative wire deadline becomes an
         absolute frontend-clock deadline HERE (one clock, the
         frontend's)."""
         deadline = (None if rq.deadline_rel is None
                     else self.frontend.clock() + rq.deadline_rel)
+        if tracing():
+            with span("rpc.submit", req=req):
+                return self.frontend.submit(rq.ctx, rq.w, k=rq.k,
+                                            deadline=deadline,
+                                            tenant=rq.tenant)
         return self.frontend.submit(rq.ctx, rq.w, k=rq.k,
                                     deadline=deadline, tenant=rq.tenant)
 
-    async def _send(self, conn: _Conn, payload: bytes) -> None:
+    async def _send(self, conn: _Conn, encode, *args, **ids) -> int:
+        """Encode ``encode(*args)`` and write it as one frame; returns
+        the ``perf_counter_ns`` stamp at which the frame was handed to
+        the transport (before the awaited drain).  ``ids`` tag the
+        ``rpc.reply`` span."""
         async with conn.wlock:
             if self._injector is not None:
                 self._injector.check("rpc_write")
-            conn.writer.write(frame(payload))
+            if tracing():
+                with span("rpc.reply", **ids):
+                    conn.writer.write(frame(encode(*args)))
+            else:
+                conn.writer.write(frame(encode(*args)))
+            t_written = time.perf_counter_ns()
             await conn.writer.drain()
+        return t_written
 
 
 def serve_in_thread(frontend, **kwargs) -> RpcServer:

@@ -48,10 +48,14 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def _assert_compiles(fn, sharding, *shapes):
+def _assert_compiles(fn, sharding, *shapes, kernel=None):
+    """Compile for the described chip; ``kernel`` is the name the Pallas
+    call must carry into the program (a device trace's event name)."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    if kernel is not None:
+        assert f"%{kernel}." in text, f"no op named {kernel}"
 
 
 def _corpus_shapes(Bq):
@@ -65,14 +69,14 @@ def test_corpus_topk_compiles_for_v5e(one_chip, no_compile_cache, Bq):
     _assert_compiles(
         lambda Q, a, e, P, aC, v: ops.dplr_corpus_score(
             Q, a, e, P, aC, v, topk=TOPK, interpret=False),
-        one_chip, *_corpus_shapes(Bq))
+        one_chip, *_corpus_shapes(Bq), kernel="dplr_corpus_score_topk")
 
 
 def test_corpus_full_mode_compiles_for_v5e(one_chip, no_compile_cache):
     _assert_compiles(
         lambda Q, a, e, P, aC, v: ops.dplr_corpus_score(
             Q, a, e, P, aC, v, interpret=False),
-        one_chip, *_corpus_shapes(16))
+        one_chip, *_corpus_shapes(16), kernel="dplr_corpus_score_full")
 
 
 def test_corpus_multi_compiles_for_v5e(one_chip, no_compile_cache):
@@ -82,7 +86,8 @@ def test_corpus_multi_compiles_for_v5e(one_chip, no_compile_cache):
             (Q,) * S, (a,) * S, (v,) * S, e, P, aC, topk=TOPK,
             interpret=False),
         one_chip, ((N, RHO, K_EMB), f32), ((N,), f32), ((N,), jnp.bool_),
-        ((S, RHO), f32), ((S, Bq, RHO, K_EMB), f32), ((S, Bq), f32))
+        ((S, RHO), f32), ((S, Bq, RHO, K_EMB), f32), ((S, Bq), f32),
+        kernel="dplr_corpus_score_multi_topk")
 
 
 def test_score_items_compiles_for_v5e(one_chip, no_compile_cache):
