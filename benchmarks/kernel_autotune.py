@@ -17,8 +17,8 @@ leaves ``block_n=None`` inherits it.  This driver pins the claims to CI:
     no padding) the winner's best-of-repeats time beats the default tile
     by >= 5%.  Candidates
     and the default obey the scoped-VMEM rule
-    (``blocks.corpus_vmem_tile``: rows x block_n <= 4096, so up to 4096
-    at Bq = 1) that every ``block_n=None`` call is capped by, so the
+    (``blocks.corpus_vmem_tile``: rows x block_n <= 32768) that every
+    ``block_n=None`` call is capped by, so the
     sweep times only tiles serving can run;
   * **registry wiring** — after the sweep, ``blocks.corpus_tile`` (what
     ``ops.dplr_corpus_score`` consults when ``block_n=None``) resolves

@@ -41,17 +41,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Named default tile sizes (retune here, not at call sites).  Values are
 # VMEM-budget choices for the f32 shapes documented in each kernel.
-CORPUS_TILE_N = 2048    # dplr_corpus_score: item-axis tile of (n, rho, k)
+CORPUS_TILE_N = 2048    # dplr_corpus_score: item (lane) tile of (rho, k, n)
 ITEM_TILE_N = 1024      # dplr_score_items: item-axis tile of (n, mI, k)
 PAIRWISE_TILE_B = 512   # fwfm_pairwise: example-axis tile of (B, m, k)
 ATTN_TILE = 128         # flash_attention: q/k row tile (MXU lane width)
 
-# Scoped-VMEM rule of the corpus scorer.  Its (rows, block_n, rho, k) f32
-# intermediate pads the minor (rho, k) dims to one (8, 128) tile: 4 KiB per
-# (query row, item) pair.  The TPU compiler's default scoped VMEM (16 MiB
-# on v5e) holds 4096 such pairs: rows x block_n = 4096 compiles at every
-# rows in 1..16 for the v5e, and 8192 runs out of VMEM.
-CORPUS_VMEM_PAIRS = 4096
+# Scoped-VMEM rule of the corpus scorer.  Items sit on lanes: per rank its
+# (rows, k, block_n) f32 intermediate is dense, 4k = 64 bytes per (query
+# row, item) pair at k = 16, a few such arrays live at once, and the
+# merge's (rows, block_n) arrays pad rows to 8 sublanes.  Compiled for
+# the v5e (16 MiB default scoped VMEM; top-K, K 16, rho 3, k 16, 2^20
+# slots), the largest power-of-two tile is 32768 at 1 row, 16384 at 2 and
+# 4, 8192 at 8 and 16, 4096 at 32, 2048 at 64, 1024 at 128: rows x
+# block_n = 32768 compiles at every rows in 1..128, and 65536 runs out of
+# VMEM at 1 and 2 rows.
+CORPUS_VMEM_PAIRS = 32768
 
 # Bounded log of tile clamps (requested > axis length).  Appended at
 # trace time by clamp_tile; drained by the autotuner / benchmarks.
@@ -91,11 +95,12 @@ _TUNED_FAMILY: dict[tuple, tuple[int, str]] = {}
 
 def corpus_vmem_tile(block_n: int, rows: int) -> int:
     """The largest power-of-two corpus tile <= ``block_n`` whose
-    ``(rows, tile, rho, k)`` working set fits ``CORPUS_VMEM_PAIRS``
-    (never below 8, the f32 sublane count).  ``rows`` is the query rows
-    one launch scores: Bq, or S * Bq for the multi-segment kernel."""
-    cap = max(8, 1 << (max(CORPUS_VMEM_PAIRS // max(rows, 1), 1)
-                       .bit_length() - 1))
+    ``rows x tile`` pairs fit ``CORPUS_VMEM_PAIRS`` (never below 128, the
+    lane count: the tile is the lane axis of its blocks).  ``rows`` is
+    the query rows one launch scores: Bq, or S * Bq for the multi-segment
+    kernel."""
+    cap = max(128, 1 << (max(CORPUS_VMEM_PAIRS // max(rows, 1), 1)
+                         .bit_length() - 1))
     return min(block_n, cap)
 
 
@@ -159,11 +164,13 @@ def row_tiles(tile: int, *rest: int) -> pl.BlockSpec:
     return pl.BlockSpec((tile, *rest), lambda i: (i, *trailing))
 
 
-def col_tiles(lead: int, tile: int) -> pl.BlockSpec:
-    """``(lead, tile)`` block, axis 1 tiled by the 1-D grid step, axis 0
-    whole — the output layout of a scorer that revisits all queries per
-    item tile."""
-    return pl.BlockSpec((lead, tile), lambda i: (0, i))
+def col_tiles(*shape: int) -> pl.BlockSpec:
+    """``shape`` block, the LAST axis tiled by the 1-D grid step, every
+    leading axis whole: grid step ``i`` sees columns ``[i*tile,
+    (i+1)*tile)`` — a lane-dense operand or output whose tiled axis sits
+    on the vreg's lanes."""
+    leading = (0,) * (len(shape) - 1)
+    return pl.BlockSpec(tuple(shape), lambda i: (*leading, i))
 
 
 def broadcast(*shape: int) -> pl.BlockSpec:

@@ -13,11 +13,21 @@ Per (query q, item i) the score is then
     score[q, i] = a_C[q] + a_I[i] + 0.5 * sum_r e_r ||P_C[q, r] + Q_I[i, r]||^2
 
 with ``P_C (Bq, rho, k)`` / ``a_C (Bq,)`` the per-query context cache.  The
-kernel tiles the ITEM axis: one grid step holds a ``(block_n, rho, k)``
-slab of Q_I in VMEM, so HBM traffic is ONE pass over ``(n, rho, k)`` —
-strictly less than the ``(n, m_I, k)`` pass of ``dplr_score.py`` (the
-Algorithm-1 kernel that still re-projects item embeddings per query), by
-the factor m_I / rho (~12x for the paper's deployed geometry).
+kernel tiles the ITEM axis: one grid step holds a ``(rho, k, block_n)``
+tile of ``Q_T = transpose(Q_I)`` in VMEM, so HBM traffic is ONE pass over
+the slab — strictly less than the ``(n, m_I, k)`` pass of
+``dplr_score.py`` (the Algorithm-1 kernel that still re-projects item
+embeddings per query), by the factor m_I / rho (~12x for the paper's
+deployed geometry).
+
+Layout: the item axis sits on the vreg's 128 lanes and k on its
+sublanes, so every add, square and k-sum of the scoring runs on full
+vregs (a ``(.., rho, k)`` minor pair would pad (3, 16) to one (8, 128)
+tile, 21x).  The wrapper transposes the slab once per launch; ``a_I`` and
+the liveness mask enter as ``(1, n)`` rows, ``P_C`` as ``(rho, rows, k,
+1)`` so each query's k-vector broadcasts across lanes, and the tile's
+scores come out ``(rows, block_n)`` — the layout of the merge and of the
+full-mode output.
 
 Two output modes:
   * full   — ``(Bq, n)`` logits, out block revisited per item tile.
@@ -51,8 +61,8 @@ Accumulation dtype: ``acc_dtype='bfloat16'`` runs the O(Bq n rho k)
 eigen-weighted square-sum reduction in bf16 (halving the MXU/VPU input
 traffic where the slab dtype already sacrificed the precision) and
 upcasts to f32 BEFORE masking and the running top-K merge, so sentinel
-comparisons and tie-breaking stay exact.  The default ``'float32'`` is
-byte-identical to the historical kernel.  The autotuner sweeps this
+comparisons and tie-breaking stay exact.  The default ``'float32'``
+keeps every step in f32.  The autotuner sweeps this
 knob only for bf16 slabs; scores are tolerance-gated, not bit-exact.
 
 Multi-segment mode: ``dplr_corpus_score_multi`` scores S tenants'
@@ -81,33 +91,33 @@ from repro.kernels import blocks
 NEG_INF = -1e30
 
 
-def _rank_reduce(pp, e, acc_dtype):
-    """``sum_{r,k} e[..., r] * pp[..., r, k]`` over the trailing (rho, k)
-    axes, in the requested accumulation dtype, as VPU multiplies and
-    sums: an in-kernel matmul would run at the TPU's default f32 matmul
-    precision (a single bf16 pass), far outside the f32 contract.
-    ``e`` broadcasts as ``(rows, 1, rho)``."""
-    sq = jnp.sum(pp.astype(acc_dtype), axis=-1)            # (q, n, rho)
-    return jnp.sum(sq * e.astype(acc_dtype), axis=-1).astype(jnp.float32)
-
-
 def _tile_scores(q, a_i, e, pc, a_c, m, acc_dtype=jnp.float32):
-    """(Bq, block_n) scores for one item tile.  All operands f32 in VMEM;
-    ``m`` is the tile's (block_n,) {0,1} validity mask — dead slots are
-    pinned to exactly NEG_INF so they can never win a top-K slot."""
-    # p: (Bq, bn, rho, k) — direct fused form, same reduction order as the
-    # jnp reference so corpus-cached parity stays at float32 epsilon.
-    p = pc[:, None, :, :] + q[None, :, :, :]
-    term_e = _rank_reduce(p * p, e[None, None, :], acc_dtype)
-    s = a_c[:, None] + a_i[None, :] + 0.5 * term_e
-    return jnp.where((m != 0)[None, :], s, NEG_INF)
+    """``(rows, block_n)`` scores of one item tile, items on lanes.
+
+    ``q (rho, k, block_n)`` is the tile of ``Q_T``, ``a_i``/``m`` its
+    ``(1, block_n)`` item scalars and {0,1} liveness, ``e (rows, rho)``
+    each query row's eigen-weights, ``pc (rho, rows, k, 1)`` and ``a_c
+    (rows, 1)`` the context side.  Per rank: ``sum_k (P + Q)^2`` in the
+    direct fused form, then the e-weighted sum over rho, in
+    ``acc_dtype``, as VPU adds and multiplies: an in-kernel matmul would
+    run at the TPU's default f32 matmul precision (a single bf16 pass),
+    far outside the f32 contract.  Dead slots are pinned to exactly
+    NEG_INF so they can never win a top-K slot."""
+    term = None
+    for r in range(q.shape[0]):
+        d = q[r][None] + pc[r]                            # (rows, k, bn)
+        sq = jnp.sum((d * d).astype(acc_dtype), axis=1)   # (rows, bn)
+        t = sq * e[:, r:r + 1].astype(acc_dtype)
+        term = t if term is None else term + t
+    s = a_c + a_i + 0.5 * term.astype(jnp.float32)
+    return jnp.where(m != 0, s, NEG_INF)
 
 
 def _kernel_full(q_ref, a_ref, e_ref, pc_ref, ac_ref, m_ref, out_ref, *,
                  acc_dtype):
     out_ref[...] = _tile_scores(
-        q_ref[...], a_ref[:, 0], e_ref[:, 0], pc_ref[...], ac_ref[:, 0],
-        m_ref[:, 0], acc_dtype)
+        q_ref[...], a_ref[...], e_ref[...], pc_ref[...], ac_ref[...],
+        m_ref[...], acc_dtype)
 
 
 def _merge_topk(val_ref, idx_ref, scores, labels):
@@ -153,8 +163,12 @@ def _merge_topk(val_ref, idx_ref, scores, labels):
 
 
 def _kernel_topk(q_ref, a_ref, e_ref, pc_ref, ac_ref, m_ref, off_ref,
-                 val_ref, idx_ref, *, block_n: int, index_stride: int,
-                 acc_dtype):
+                 val_ref, idx_ref, *, seg_tiles: tuple, Bq: int,
+                 block_n: int, index_stride: int, acc_dtype):
+    """One item tile's scores merged into the running top-K.  With S > 1
+    segments (``seg_tiles`` holds each one's static ``[first, stop)``
+    tile range) the rows are S stacked micro-batches of ``Bq`` and a tile
+    counts only for its own segment's rows."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -163,13 +177,91 @@ def _kernel_topk(q_ref, a_ref, e_ref, pc_ref, ac_ref, m_ref, off_ref,
         idx_ref[...] = jnp.zeros_like(idx_ref)
 
     scores = _tile_scores(
-        q_ref[...], a_ref[:, 0], e_ref[:, 0], pc_ref[...], ac_ref[:, 0],
-        m_ref[:, 0], acc_dtype)
-    # row r of this tile is local slot i*block_n + r; the emitted index is
-    # its caller-defined global label off + stride * local.
+        q_ref[...], a_ref[...], e_ref[...], pc_ref[...], ac_ref[...],
+        m_ref[...], acc_dtype)
+    # column c of this tile is segment-local slot row_base + c; the
+    # emitted index is its caller-defined label off + stride * local
+    row_base = i * block_n
+    if len(seg_tiles) > 1:
+        # which segment this tile belongs to, from the static tile
+        # boundaries: its stacked query rows [q_off, q_off + Bq) and its
+        # first segment-LOCAL item row
+        q_off = 0
+        for s, (first, stop) in enumerate(seg_tiles):
+            in_seg = (i >= first) & (i < stop)
+            q_off = jnp.where(in_seg, s * Bq, q_off)
+            row_base = jnp.where(in_seg, (i - first) * block_n, row_base)
+        qidx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+        own = (qidx >= q_off) & (qidx < q_off + Bq)
+        # a foreign row sees this tile as all-NEG_INF, so its running
+        # top-K is untouched by neighbor segments' item tiles
+        scores = jnp.where(own, scores, NEG_INF)
     labels = off_ref[0] + index_stride * (
-        i * block_n + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
+        row_base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
     _merge_topk(val_ref, idx_ref, scores, labels)
+
+
+def _item_rows(Q_I, a_I, valid, pad):
+    """The item side, lane-dense: ``Q_T (rho, k, n + pad)`` and ``(1, n +
+    pad)`` rows of ``a_I`` and the int32 liveness mask; phantom padding
+    is dead."""
+    n = Q_I.shape[0]
+    mask = (jnp.ones((n,), jnp.int32) if valid is None
+            else jnp.asarray(valid).astype(jnp.int32))
+    Q_T = jnp.transpose(Q_I.astype(jnp.float32), (1, 2, 0))
+    return (jnp.pad(Q_T, ((0, 0), (0, 0), (0, pad))),
+            jnp.pad(a_I.astype(jnp.float32), (0, pad))[None],
+            jnp.pad(mask, (0, pad))[None])
+
+
+def _context_args(e_rows, P_C, a_C):
+    """The query side for ``rows`` stacked query rows: ``e (rows, rho)``,
+    ``P_C (rho, rows, k, 1)`` (a query's k-vector on sublanes, broadcast
+    across the item lanes) and ``a_C (rows, 1)``."""
+    pc = jnp.transpose(P_C.astype(jnp.float32), (1, 0, 2))[..., None]
+    return e_rows.astype(jnp.float32), pc, a_C.astype(jnp.float32)[:, None]
+
+
+def _in_specs(rho, k, rows, block_n):
+    """Blocks of ``(Q_T, a_I, e, P_C, a_C, mask)``: item operands tiled
+    on the lane axis, query operands whole and VMEM-resident."""
+    return [
+        blocks.col_tiles(rho, k, block_n),
+        blocks.col_tiles(1, block_n),
+        blocks.broadcast(rows, rho),
+        blocks.broadcast(rho, rows, k, 1),
+        blocks.broadcast(rows, 1),
+        blocks.col_tiles(1, block_n),
+    ]
+
+
+def _topk_call(args, *, seg_tiles, Bq, topk, block_n, index_offset,
+               index_stride, acc_dtype, interpret, name):
+    """The fused top-K ``pallas_call`` over ``args = (Q_T, a_I, e, P_C,
+    a_C, mask)`` laid out by ``_item_rows`` / ``_context_args``."""
+    rho, k, n_pad = args[0].shape
+    rows = args[2].shape[0]
+    off = jnp.asarray(index_offset, jnp.int32).reshape(1)
+    kernel = functools.partial(_kernel_topk, seg_tiles=seg_tiles, Bq=Bq,
+                               block_n=block_n, index_stride=index_stride,
+                               acc_dtype=acc_dtype)
+    return pl.pallas_call(
+        kernel,
+        grid=blocks.grid_1d(n_pad, block_n),
+        in_specs=_in_specs(rho, k, rows, block_n) + [blocks.smem()],
+        out_specs=[
+            # constant index map => the running (values, indices) pair
+            # stays VMEM-resident across every item tile
+            blocks.broadcast(rows, topk),
+            blocks.broadcast(rows, topk),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((rows, topk), jnp.float32),
+            jax.ShapeDtypeStruct((rows, topk), jnp.int32),
+        ],
+        interpret=interpret,
+        name=name,
+    )(*args, off)
 
 
 @functools.partial(jax.jit,
@@ -201,122 +293,42 @@ def dplr_corpus_score(
     ``axis_index`` inside ``shard_map``); the stride is static.
 
     ``acc_dtype``: accumulation dtype of the rank-space reduction
-    (``'float32'`` default = historical bit-exact path; ``'bfloat16'``
+    (``'float32'`` default = every step in f32; ``'bfloat16'``
     trades the reduction's precision for bandwidth — autotuner-gated,
     tolerance-bounded vs the oracle, never used on f32 slabs)."""
     n, rho, k = Q_I.shape
     Bq = P_C.shape[0]
     acc = jnp.dtype(acc_dtype)
-    Q_I = Q_I.astype(jnp.float32)
-    a_I = a_I.astype(jnp.float32)
-    e = e.astype(jnp.float32)
-    P_C = P_C.astype(jnp.float32)
-    a_C = a_C.astype(jnp.float32)
-    mask = (jnp.ones((n,), jnp.int32) if valid is None
-            else jnp.asarray(valid).astype(jnp.int32))
-
     block_n = blocks.clamp_tile(block_n, n)
     pad = blocks.pad_amount(n, block_n)
-    if pad:
-        Q_I = jnp.pad(Q_I, ((0, pad), (0, 0), (0, 0)))
-        a_I = jnp.pad(a_I, (0, pad))
-        mask = jnp.pad(mask, (0, pad))      # phantom rows are dead slots
-    n_pad = n + pad
-    grid = blocks.grid_1d(n_pad, block_n)
-
-    in_specs = [
-        blocks.row_tiles(block_n, rho, k),
-        blocks.row_tiles(block_n, 1),
-        blocks.broadcast(rho, 1),
-        blocks.broadcast(Bq, rho, k),
-        blocks.broadcast(Bq, 1),
-        blocks.row_tiles(block_n, 1),
-    ]
-    args = (Q_I, a_I[:, None], e[:, None], P_C, a_C[:, None], mask[:, None])
+    Q_T, a_row, m_row = _item_rows(Q_I, a_I, valid, pad)
+    args = (Q_T, a_row,
+            *_context_args(jnp.broadcast_to(e[None], (Bq, rho)), P_C, a_C),
+            m_row)
 
     if topk is None:
         return pl.pallas_call(
             functools.partial(_kernel_full, acc_dtype=acc),
-            grid=grid,
-            in_specs=in_specs,
+            grid=blocks.grid_1d(n + pad, block_n),
+            in_specs=_in_specs(rho, k, Bq, block_n),
             out_specs=blocks.col_tiles(Bq, block_n),
-            out_shape=jax.ShapeDtypeStruct((Bq, n_pad), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((Bq, n + pad), jnp.float32),
             interpret=interpret,
             name="dplr_corpus_score_full",
         )(*args)[:, :n]
 
     if not 0 < topk <= n:
         raise ValueError(f"topk={topk} out of range for n={n}")
-    off = jnp.asarray(index_offset, jnp.int32).reshape(1)
-    in_specs = in_specs + [blocks.smem()]
-    args = args + (off,)
-    kernel = functools.partial(_kernel_topk, block_n=block_n,
-                               index_stride=index_stride, acc_dtype=acc)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            # constant index map => the running (values, indices) pair
-            # stays VMEM-resident across every item tile
-            blocks.broadcast(Bq, topk),
-            blocks.broadcast(Bq, topk),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bq, topk), jnp.float32),
-            jax.ShapeDtypeStruct((Bq, topk), jnp.int32),
-        ],
-        interpret=interpret,
-        name="dplr_corpus_score_topk",
-    )(*args)
+    return _topk_call(
+        args, seg_tiles=((0, (n + pad) // block_n),), Bq=Bq,
+        topk=topk, block_n=block_n, index_offset=index_offset,
+        index_stride=index_stride, acc_dtype=acc, interpret=interpret,
+        name="dplr_corpus_score_topk")
 
 
 # ---------------------------------------------------------------------------
 # Multi-segment mode: S tenants' micro-batches in ONE launch
 # ---------------------------------------------------------------------------
-
-def _tile_scores_multi(q, a_i, e_q, pc, a_c, m, acc_dtype=jnp.float32):
-    """(SB, block_n) scores of one item tile against EVERY stacked query
-    row — the per-query ``e_q`` carries each row's own segment's eigen-
-    weights, so foreign rows compute garbage that the caller masks to
-    NEG_INF before the merge (they can never win a slot)."""
-    p = pc[:, None, :, :] + q[None, :, :, :]
-    term_e = _rank_reduce(p * p, e_q[:, None, :], acc_dtype)
-    s = a_c[:, None] + a_i[None, :] + 0.5 * term_e
-    return jnp.where((m != 0)[None, :], s, NEG_INF)
-
-
-def _kernel_multi_topk(q_ref, a_ref, m_ref, eq_ref, pc_ref, ac_ref,
-                       off_ref, val_ref, idx_ref, *, seg_tiles: tuple,
-                       Bq: int, block_n: int, index_stride: int,
-                       acc_dtype):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        val_ref[...] = jnp.full_like(val_ref, NEG_INF)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-
-    scores = _tile_scores_multi(
-        q_ref[...], a_ref[:, 0], eq_ref[...], pc_ref[...], ac_ref[:, 0],
-        m_ref[:, 0], acc_dtype)
-    # which segment this tile belongs to, from the static tile boundaries:
-    # its stacked query rows [q_off, q_off + Bq) and its first segment-
-    # LOCAL item row
-    q_off, row_base = 0, 0
-    for s, (first, stop) in enumerate(seg_tiles):
-        in_seg = (i >= first) & (i < stop)
-        q_off = jnp.where(in_seg, s * Bq, q_off)
-        row_base = jnp.where(in_seg, (i - first) * block_n, row_base)
-    qidx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-    own = (qidx >= q_off) & (qidx < q_off + Bq)
-    # a foreign row sees this tile as all-NEG_INF, so its running top-K
-    # is untouched by neighbor segments' item tiles (segment isolation)
-    scores = jnp.where(own, scores, NEG_INF)
-    labels = off_ref[0] + index_stride * (
-        row_base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-    _merge_topk(val_ref, idx_ref, scores, labels)
-
 
 @functools.partial(jax.jit,
                    static_argnames=("topk", "block_n", "interpret",
@@ -369,7 +381,6 @@ def dplr_corpus_score_multi(
     rho, k = Q_parts[0].shape[1:]
     Bq = P_C.shape[1]
     SB = S * Bq
-    acc = jnp.dtype(acc_dtype)
     n_min = min(int(q.shape[0]) for q in Q_parts)
     if not 0 < topk <= n_min:
         raise ValueError(f"topk={topk} out of range for smallest segment "
@@ -377,60 +388,21 @@ def dplr_corpus_score_multi(
     block_n = blocks.clamp_tile(block_n, max(int(q.shape[0])
                                              for q in Q_parts))
 
-    q_cat, a_cat, m_cat, seg_tiles = [], [], [], []
+    parts, seg_tiles = [], []
     for s in range(S):
-        q_s = Q_parts[s].astype(jnp.float32)
-        a_s = a_parts[s].astype(jnp.float32)
-        n_s = q_s.shape[0]
-        m_s = (jnp.ones((n_s,), jnp.int32) if valid_parts[s] is None
-               else jnp.asarray(valid_parts[s]).astype(jnp.int32))
+        n_s = Q_parts[s].shape[0]
         pad = blocks.pad_amount(n_s, block_n)
-        if pad:
-            q_s = jnp.pad(q_s, ((0, pad), (0, 0), (0, 0)))
-            a_s = jnp.pad(a_s, (0, pad))
-            m_s = jnp.pad(m_s, (0, pad))    # phantom rows are dead slots
-        q_cat.append(q_s)
-        a_cat.append(a_s)
-        m_cat.append(m_s)
+        parts.append(_item_rows(Q_parts[s], a_parts[s], valid_parts[s], pad))
         first = seg_tiles[-1][1] if seg_tiles else 0
         seg_tiles.append((first, first + (n_s + pad) // block_n))
-    Q_cat = jnp.concatenate(q_cat)
-    a_cat = jnp.concatenate(a_cat)
-    m_cat = jnp.concatenate(m_cat)
-    grid = blocks.grid_1d(Q_cat.shape[0], block_n)
-
-    e_q = jnp.repeat(e.astype(jnp.float32), Bq, axis=0)        # (SB, rho)
-    pc = P_C.astype(jnp.float32).reshape(SB, rho, k)
-    ac = a_C.astype(jnp.float32).reshape(SB)
-    off = jnp.asarray(index_offset, jnp.int32).reshape(1)
-
-    in_specs = [
-        blocks.row_tiles(block_n, rho, k),
-        blocks.row_tiles(block_n, 1),
-        blocks.row_tiles(block_n, 1),
-        blocks.broadcast(SB, rho),
-        blocks.broadcast(SB, rho, k),
-        blocks.broadcast(SB, 1),
-        blocks.smem(),
-    ]
-    args = (Q_cat, a_cat[:, None], m_cat[:, None], e_q, pc, ac[:, None],
-            off)
-    kernel = functools.partial(_kernel_multi_topk, seg_tiles=tuple(seg_tiles),
-                               Bq=Bq, block_n=block_n,
-                               index_stride=index_stride, acc_dtype=acc)
-    vals, idx = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            blocks.broadcast(SB, topk),
-            blocks.broadcast(SB, topk),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((SB, topk), jnp.float32),
-            jax.ShapeDtypeStruct((SB, topk), jnp.int32),
-        ],
-        interpret=interpret,
-        name="dplr_corpus_score_multi_topk",
-    )(*args)
+    # segments concatenate on the lane (item) axis
+    Q_T, a_row, m_row = (jnp.concatenate(x, axis=-1) for x in zip(*parts))
+    context = _context_args(jnp.repeat(e, Bq, axis=0),
+                            P_C.reshape(SB, rho, k), a_C.reshape(SB))
+    vals, idx = _topk_call(
+        (Q_T, a_row, *context, m_row), seg_tiles=tuple(seg_tiles), Bq=Bq,
+        topk=topk, block_n=block_n, index_offset=index_offset,
+        index_stride=index_stride,
+        acc_dtype=jnp.dtype(acc_dtype), interpret=interpret,
+        name="dplr_corpus_score_multi_topk")
     return vals.reshape(S, Bq, topk), idx.reshape(S, Bq, topk)

@@ -117,6 +117,8 @@ def test_engine_topk_matches_full_scores():
     (64, 2, 8, 1, 32),
     (1000, 3, 16, 4, 256),      # non-divisible n -> padding path
     (130, 5, 16, 2, 64),
+    (300, 3, 16, 16, 128),      # lane-width tiles, ragged last tile
+    (700, 5, 16, 3, 256),
 ])
 def test_corpus_score_kernel_vs_ref(rng, n, rho, k, Bq, block_n):
     Q = jnp.asarray(rng.standard_normal((n, rho, k), dtype=np.float32))
@@ -129,12 +131,14 @@ def test_corpus_score_kernel_vs_ref(rng, n, rho, k, Bq, block_n):
     np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("n,block_n,K", [
-    (100, 32, 7),      # padding + K not a block multiple
-    (256, 64, 16),
+@pytest.mark.parametrize("n,block_n,K,rho,k", [
+    pytest.param(100, 32, 7, 3, 8, id="100-32-7"),  # padding, K ragged
+    pytest.param(256, 64, 16, 3, 8, id="256-64-16"),
+    pytest.param(300, 128, 16, 3, 16, id="300-128-16"),  # lane-width
+    pytest.param(600, 256, 16, 5, 16, id="600-256-16-rho5"),
 ])
-def test_corpus_score_kernel_topk_vs_argsort(rng, n, block_n, K):
-    rho, k, Bq = 3, 8, 3
+def test_corpus_score_kernel_topk_vs_argsort(rng, n, block_n, K, rho, k):
+    Bq = 3
     Q = jnp.asarray(rng.standard_normal((n, rho, k), dtype=np.float32))
     a_I = jnp.asarray(rng.standard_normal(n).astype(np.float32))
     e = jnp.asarray(rng.standard_normal(rho).astype(np.float32))
@@ -479,11 +483,15 @@ def test_corpus_score_acc_dtype(rng):
 
 
 @pytest.mark.parametrize("masked", [False, True])
-@pytest.mark.parametrize("ns", [(64, 64), (100, 37, 64)])
-def test_corpus_score_multi_vs_ref(rng, ns, masked):
+@pytest.mark.parametrize("ns,rho,k,block_n", [
+    pytest.param((64, 64), 3, 8, 32, id="ns0"),
+    pytest.param((100, 37, 64), 3, 8, 32, id="ns1"),
+    pytest.param((300, 129), 5, 16, 128, id="ns2-rho5-lane"),
+])
+def test_corpus_score_multi_vs_ref(rng, ns, rho, k, block_n, masked):
     """Multi-segment fused kernel == per-segment oracle, exactly —
     uneven segment sizes, non-pow2 sizes, ragged tiles."""
-    rho, k, Bq, K = 3, 8, 2, 7
+    Bq, K = 2, 7
     parts = [_corpus_inputs(rng, n, rho, k, Bq, masked) for n in ns]
     Q_parts = tuple(p[0] for p in parts)
     a_parts = tuple(p[1] for p in parts)
@@ -492,7 +500,7 @@ def test_corpus_score_multi_vs_ref(rng, ns, masked):
     PC = jnp.stack([p[3] for p in parts])
     a_C = jnp.stack([p[4] for p in parts])
     vals, idx = ops.dplr_corpus_score_multi(
-        Q_parts, a_parts, valid_parts, e, PC, a_C, topk=K, block_n=32)
+        Q_parts, a_parts, valid_parts, e, PC, a_C, topk=K, block_n=block_n)
     want_v, want_i = ref.dplr_corpus_multi_topk_ref(
         Q_parts, a_parts, valid_parts, e, PC, a_C, K)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(want_v),
@@ -502,7 +510,7 @@ def test_corpus_score_multi_vs_ref(rng, ns, masked):
     for s, (Q, a_I, es, PCs, aCs, valid) in enumerate(parts):
         v1, i1 = ops.dplr_corpus_score(Q, a_I, es, PCs, aCs,
                                        valid=valid if masked else None,
-                                       topk=K, block_n=32)
+                                       topk=K, block_n=block_n)
         np.testing.assert_array_equal(np.asarray(vals)[s], np.asarray(v1))
         np.testing.assert_array_equal(np.asarray(idx)[s], np.asarray(i1))
 

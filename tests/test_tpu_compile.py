@@ -1,5 +1,6 @@
 """The main-path Pallas kernels compile for a TPU v5e at the deployed
-widths (rank 3, k = 16, 38 item fields, a corpus of 8192 items).
+widths (rank 3, k = 16, 38 item fields, a corpus of 8192 items, and the
+retrieval slab of 2^20 slots).
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached, so these tests run on the CPU: each lowers a
@@ -13,6 +14,7 @@ persistent compilation cache is off around the compiles: a program built
 for a described chip can be written to it but not read back.
 """
 import os
+import re
 
 import pytest
 
@@ -23,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 
 RHO, K_EMB, M_I, N, TOPK = 3, 16, 38, 8192, 16
+N_RETRIEVAL = 1 << 20
 
 
 @pytest.fixture(scope="module")
@@ -56,12 +59,13 @@ def _assert_compiles(fn, sharding, *shapes, kernel=None):
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
     if kernel is not None:
         assert f"%{kernel}." in text, f"no op named {kernel}"
+    return text
 
 
-def _corpus_shapes(Bq):
+def _corpus_shapes(Bq, n=N):
     f32 = jnp.float32
-    return [((N, RHO, K_EMB), f32), ((N,), f32), ((RHO,), f32),
-            ((Bq, RHO, K_EMB), f32), ((Bq,), f32), ((N,), jnp.bool_)]
+    return [((n, RHO, K_EMB), f32), ((n,), f32), ((RHO,), f32),
+            ((Bq, RHO, K_EMB), f32), ((Bq,), f32), ((n,), jnp.bool_)]
 
 
 @pytest.mark.parametrize("Bq", [1, 16])
@@ -70,6 +74,27 @@ def test_corpus_topk_compiles_for_v5e(one_chip, no_compile_cache, Bq):
         lambda Q, a, e, P, aC, v: ops.dplr_corpus_score(
             Q, a, e, P, aC, v, topk=TOPK, interpret=False),
         one_chip, *_corpus_shapes(Bq), kernel="dplr_corpus_score_topk")
+
+
+@pytest.mark.parametrize("block_n", [128, None])
+def test_corpus_topk_retrieval_shape_compiles_for_v5e(one_chip,
+                                                      no_compile_cache,
+                                                      block_n):
+    """The retrieval slab (2^20 slots, Bq 16, K 16) compiles at its
+    configured tile and under the default tile rule, and the program
+    copies no per-slot operand: the slab enters the kernel as stored
+    (items minor) and ``a_I`` / the mask as ``(1, n)`` rows, where an
+    ``(n, 1)`` column or a ``(n, rho, k)`` relayout would be copied on
+    every launch."""
+    text = _assert_compiles(
+        lambda Q, a, e, P, aC, v: ops.dplr_corpus_score(
+            Q, a, e, P, aC, v, topk=TOPK, block_n=block_n,
+            interpret=False),
+        one_chip, *_corpus_shapes(16, N_RETRIEVAL),
+        kernel="dplr_corpus_score_topk")
+    slot_copies = re.findall(
+        rf"\[{N_RETRIEVAL},(?:1|{RHO},{K_EMB})\]\{{[^}}]*\}} copy\(", text)
+    assert not slot_copies, slot_copies
 
 
 def test_corpus_full_mode_compiles_for_v5e(one_chip, no_compile_cache):
