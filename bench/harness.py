@@ -1,19 +1,21 @@
 """One run of one cell: set up the server, drive it from the load
 generator for the window, check the replies, reduce the metrics.
 
-Everything that differs between cells comes from three files the cell
+Everything that differs between cells comes from the files the cell
 names (``BENCHMARK.json``'s ``workloads`` entry): the configuration
 (``bench/configs/<config>.json``: model factory, serving dtype, corpus,
 tenants, mesh, scorer, frontend and server options, comparison limits),
-the traffic mix (``bench/traffic/<traffic>.json``: loop, rate or
-clients, K law, context law, tenant law, bursts, writer) and one reader
-per metric (``bench/metrics/<name>.py``).  There is no branch on a cell's
-name.
+the model module the configuration names (``bench/models/<m>.py``:
+layout, weights, ids, float64 reference, work counts, kernel name), the
+traffic mix (``bench/traffic/<traffic>.json``: loop, rate or clients, K
+law, context law, tenant law, bursts, writer) and one reader per metric
+(``bench/metrics/<name>.py``).  There is no branch on a cell's name or a
+model's.
 
 The path driven is the one users are served through: the load generator
 (a child process that never imports JAX) -> loopback TCP -> ``RpcServer``
 -> ``QueryFrontend`` (pumped by the server) -> ``CorpusState`` on a
-``ScorerRuntime`` -> the Pallas kernel ``dplr_corpus_score``.
+``ScorerRuntime`` -> the model's kernel.
 """
 from __future__ import annotations
 
@@ -38,9 +40,6 @@ from bench import reference, traffic, work
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
-# what the kernel's events are called in a device trace (the custom call
-# is named after the jitted wrapper, the body after its function)
-KERNEL_EVENT = re.compile(r"dplr_corpus_score|_kernel_topk")
 
 
 # -- specification ----------------------------------------------------------
@@ -81,6 +80,20 @@ def reader(name: str):
     raise FileNotFoundError(f"no reader bench/metrics/{name}.py")
 
 
+def model_module(name: str):
+    """The module a configuration names by ``model.bench_model``:
+    ``bench/models/<name>.py`` (a relative path from there may name one
+    elsewhere under ``bench``, as the tests' modules do), loaded once."""
+    path = os.path.normpath(os.path.join(BENCH, "models", name + ".py"))
+    key = "bench_model_" + re.sub(r"\W", "_", os.path.relpath(path, BENCH))
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        spec.loader.exec_module(mod)
+    return sys.modules[key]
+
+
 # -- what a metric reader sees ----------------------------------------------
 
 @dataclasses.dataclass
@@ -91,9 +104,9 @@ class Run:
     replies_in_window: int        # replies received inside the window
     write_ms: np.ndarray          # catalogue writes called in the window
     frontend: dict                # QueryFrontend.stats change over window
-    request_flops: float          # work.request_flops of this cell
+    request_flops: float          # the model's FLOPs of one request
     launch_flops: float           # kernel FLOPs of a launch at mean rows
-    launch_bytes: float           # work.launch_bytes at the mean rows
+    launch_bytes: float           # kernel HBM bytes of a launch at mean rows
     peak: dict                    # work.peaks of the device kind
     trace: dict | None            # devtrace.reduce of the traced window
 
@@ -124,51 +137,19 @@ class Compiles:
         return sum(lo <= t <= hi for t in self.times)
 
 
-def make_weights(lay: reference.Layout, seed: int):
-    """Two params snapshots of the model, drawn on the device in one
-    jitted call: the served one and the one a mid-window refresh
-    installs (sharing the embedding arena).  The laws follow the model's
-    ``fwfm.init`` (embeddings N(0, 1/k), U = noise/sqrt(m) + 1 on rank
-    0), with non-zero first-order weights and eigen-weights so every
-    term of the score is exercised."""
-    import jax
-    import jax.numpy as jnp
-
-    m = lay.m_ctx + lay.m_item
-    state = np.random.SeedSequence([int(seed), traffic.WEIGHTS])
-    key = jax.random.PRNGKey(int(state.generate_state(1)[0]))
-
-    @jax.jit
-    def draw(key):
-        ks = jax.random.split(key, 7)
-        emb = jax.random.normal(ks[0], (lay.rows, lay.k)) / np.sqrt(lay.k)
-
-        def snapshot(k_lin, k_u, k_e, bias, e_spread):
-            U = jax.random.normal(k_u, (lay.rank, m)) / np.sqrt(m)
-            return {
-                "bias": jnp.asarray(bias, jnp.float32),
-                "linear": 0.1 * jax.random.normal(k_lin, (lay.rows,)),
-                "embedding": emb,
-                "U": U.at[0].add(1.0),
-                "e": 1.0 + e_spread * jax.random.uniform(
-                    k_e, (lay.rank,), minval=-1.0, maxval=1.0),
-            }
-        return (snapshot(ks[1], ks[2], ks[3], 0.0, 0.0),
-                snapshot(ks[4], ks[5], ks[6], 0.5, 0.5))
-
-    return draw(key)
-
-
 class Writer:
     """The catalogue writer of a mix with a ``writer`` block: at its rate,
     cycling remove / add / update of ``items`` slots each, taken from
-    the hot slots (those ranked at the top for the most popular
+    the hot slots (those served at the top for the most popular
     contexts, where churn changes replies), and one model refresh at
-    ``refresh_at`` of the window.  Logs every call into the tenant's
-    ``reference.Catalogue``; write latency is call to return."""
+    ``refresh_at`` of the window; an update writes ``item_rows(n, g)``.
+    Logs every call into the tenant's ``reference.Catalogue``; write
+    latency is call to return."""
 
-    def __init__(self, fe, spec, hot, slab, cat, params1, tenant, seed):
+    def __init__(self, fe, spec, item_rows, hot, slab, cat, params1, tenant,
+                 seed):
         self.fe, self.spec, self.hot, self.slab = fe, spec, hot, slab
+        self.item_rows = item_rows
         self.cat, self.params1, self.tenant = cat, params1, tenant
         self.g = traffic.rng(seed, traffic.WRITER)
         self.n = int(spec["items"])
@@ -222,8 +203,7 @@ class Writer:
                     self.hot[self.hot.index(int(o))] = int(s)
             elif op == "update":
                 slots = self._pair()
-                ids = traffic.id_rows(self.spec["item_tiers"], self.n,
-                                      self.g, self.spec["zipf_a"])
+                ids = self.item_rows(self.n, self.g)
                 self.fe.update_items(slots, ids, **kw)
                 done = time.monotonic()
                 self.slab[slots] = ids
@@ -241,27 +221,13 @@ class Writer:
                 if wait > 0:
                     time.sleep(wait)
                 self.step(op)
-        except BaseException as e:     # noqa: BLE001 — reported by the run
-            self.error = e
+        except BaseException as exc:   # noqa: BLE001 — reported by the run
+            self.error = exc
 
 
 def _factory(path: str):
     mod, fn = path.split(":")
     return getattr(importlib.import_module(mod), fn)
-
-
-def _check_layout(cfg, lay: reference.Layout) -> None:
-    """The program's layout is the one the reference was given."""
-    got = cfg.layout
-    vocab = [f.vocab_size for f in got.fields]
-    offsets = np.asarray(got.field_offsets)
-    want = np.concatenate([lay.off_ctx, lay.off_item])
-    if (got.n_context, got.n_item, cfg.embed_dim, cfg.rank) != (
-            lay.m_ctx, lay.m_item, lay.k, lay.rank) or \
-            vocab != lay.v_ctx + lay.v_item or \
-            not np.array_equal(offsets, want):
-        raise ValueError("the model factory's layout differs from the "
-                         "configuration file's tiers")
 
 
 def _start_loadgen(header: dict, pool: np.ndarray):
@@ -326,9 +292,10 @@ class Served:
         self.config, self.seed = config, seed
 
         model, corpus = config["model"], config["corpus"]
-        self.lay = lay = reference.Layout(model)
+        self.model = mod = model_module(model["bench_model"])
+        self.lay = lay = mod.Layout(model)
         cfg = _factory(model["factory"])()
-        _check_layout(cfg, lay)
+        mod.check(cfg, lay)
         cfg = dataclasses.replace(
             cfg, dtype=jax.numpy.dtype(control or model["dtype"]))
         zipf_a = float(corpus["id_zipf_a"])
@@ -340,17 +307,16 @@ class Served:
 
         # -- build: weights, corpora, engine states, frontend -------------
         t = time.monotonic()
-        self.snaps = make_weights(lay, seed)
+        self.snaps = mod.draw(lay, seed)
         served = self.snaps
         if control:
             served = tuple(jax.tree.map(lambda a: a.astype(cfg.dtype), s)
                            for s in self.snaps)
         g_items = traffic.rng(seed, traffic.ITEMS)
-        items = {nm: traffic.id_rows(model["item_tiers"], n_items, g_items,
-                                     zipf_a) for nm in names}
-        self.pool = traffic.id_rows(model["context_tiers"],
-                                    int(corpus["context_pool"]),
-                                    traffic.rng(seed, traffic.POOL), zipf_a)
+        items = {nm: mod.item_rows(model, n_items, g_items, zipf_a)
+                 for nm in names}
+        self.pool = mod.query_rows(model, int(corpus["context_pool"]),
+                                   traffic.rng(seed, traffic.POOL), zipf_a)
         mesh = (make_host_mesh(model=int(config["mesh"]["model"]))
                 if config.get("mesh") else None)
         self.runtime = ScorerRuntime(cfg, mesh=mesh, **config["scorer"])
@@ -374,26 +340,27 @@ class Served:
         bqs = [1 << i for i in range(max_batch.bit_length())]
         ks = [k for k in traffic.k_buckets(mix["k"]) if k <= max_k]
         first = self.states[names[0]]
-        m_ctx = lay.m_ctx
+        width = lay.query_width
         for bq in bqs:
             ids = np.ascontiguousarray(np.broadcast_to(self.pool[0],
-                                                       (bq, m_ctx)))
-            w = np.ones((bq, m_ctx), np.float32)
+                                                       (bq, width)))
+            w = np.ones((bq, width), np.float32)
             for k in ks:
                 jax.block_until_ready(first.topk(ids, k, w))
         self.cats = {nm: reference.Catalogue(items[nm], capacity)
                      for nm in names}
         self.writer = None
         if mix.get("writer"):
-            spec = dict(mix["writer"], item_tiers=model["item_tiers"],
-                        zipf_a=zipf_a)
+            spec = mix["writer"]
             nm = names[0]
             hot_ids = np.ascontiguousarray(self.pool[:max_batch])
             _, hot = first.topk(hot_ids, max_k,
                                 np.ones(hot_ids.shape, np.float32))
             order = list(dict.fromkeys(np.asarray(hot).reshape(-1).tolist()))
-            self.writer = Writer(fe, spec, order, items[nm].copy(),
-                                 self.cats[nm], served[1], nm or None, seed)
+            self.writer = Writer(
+                fe, spec, lambda n, g: mod.item_rows(model, n, g, zipf_a),
+                order, items[nm].copy(), self.cats[nm], served[1],
+                nm or None, seed)
             # one cycle, and a refresh there and back, before the window:
             # the writes' programs compile now, not inside it
             for op in spec["ops"]:
@@ -422,7 +389,7 @@ class Served:
         header = {"host": "127.0.0.1", "port": self.server.port,
                   "seed": int(self.seed), "seconds": float(seconds),
                   "warmup_s": float(mix["warmup_s"]), "mix": mix,
-                  "pool": len(self.pool), "n_ctx": self.lay.m_ctx,
+                  "pool": len(self.pool), "n_ctx": self.lay.query_width,
                   "tenants": self.names, "max_k": self.max_k}
         proc = _start_loadgen(header, self.pool)
         trace_dir = None
@@ -585,12 +552,11 @@ def run_cell(config: dict, mix: dict, metric_specs: list,
         raw = devtrace.load(trace_dir)
         if keep_trace:
             _keep(raw, keep_trace)
-        summary = devtrace.reduce(raw, KERNEL_EVENT)
+        summary = devtrace.reduce(raw, sv.model.KERNEL_EVENT)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        lay = sv.lay
         _, bound = work.roofline_seconds(
-            rows * sv.n_items * work.pair_flops(lay.rank, lay.k),
-            work.launch_bytes(sv.capacity, rows, sv.max_k, lay.rank, lay.k),
+            sv.model.launch_flops(sv.lay, sv.n_items, rows),
+            sv.model.launch_bytes(sv.lay, sv.capacity, rows, sv.max_k),
             sv.peak)
         log(f"trace: window {summary['window_s']:.6f} s, busy "
             f"{summary['busy_s']:.6f} s, kernel {summary['kernel_s']:.6f} s "
@@ -611,15 +577,13 @@ def run_cell(config: dict, mix: dict, metric_specs: list,
         f"write or refresh) in {time.monotonic() - t_ref:.3f} s")
     correct = all(v <= lim for v, lim in checks.values())
 
-    lay = sv.lay
+    mod, lay = sv.model, sv.lay
     run = Run(seconds=seconds, setup_s=sv.setup_s, latency_ms=lat_ms,
               replies_in_window=in_win, write_ms=write_ms, frontend=delta,
-              request_flops=work.request_flops(sv.n_items, lay.m_ctx,
-                                               lay.rank, lay.k),
-              launch_flops=rows * sv.n_items * work.pair_flops(lay.rank,
-                                                               lay.k),
-              launch_bytes=work.launch_bytes(sv.capacity, rows, sv.max_k,
-                                             lay.rank, lay.k),
+              request_flops=mod.request_flops(lay, sv.n_items),
+              launch_flops=mod.launch_flops(lay, sv.n_items, rows),
+              launch_bytes=mod.launch_bytes(lay, sv.capacity, rows,
+                                            sv.max_k),
               peak=sv.peak, trace=summary)
     metrics = {}
     for m in metric_specs:
@@ -645,9 +609,9 @@ def _keep(raw: dict, path: str, span_ns: int = 200_000_000) -> None:
     lo = raw["window"][0]
     hi = lo + span_ns
     keep = {"window": [lo, hi],
-            "device": {p: [e for e in evs if lo <= e[1] < hi]
+            "device": {p: [ev for ev in evs if lo <= ev[1] < hi]
                        for p, evs in raw["device"].items()},
-            "host": [e for e in raw["host"] if lo <= e[2] < hi]}
+            "host": [ev for ev in raw["host"] if lo <= ev[2] < hi]}
     with open(path, "w") as f:
         json.dump(keep, f)
 
@@ -658,16 +622,13 @@ def compare(sv: Served, replies, answered, t0, t_end):
     version that may have served it and keeps its best reading.  Returns
     (checks, replies checked, of them served after a write or refresh)."""
     lim = sv.config["checks"]
-    lay, pool = sv.lay, sv.pool
+    pool = sv.pool
     idx = np.flatnonzero(answered & (replies["sched"] >= t0)
                          & (replies["sched"] <= t_end))
     n = min(int(lim["sample"]), len(idx))
     idx = np.sort(traffic.rng(sv.seed, traffic.SAMPLE).choice(
         idx, n, replace=False))
-    emb = np.asarray(sv.snaps[0]["embedding"])
-    weights = [reference.Weights(lay, emb, *(np.asarray(s[f]) for f in
-                                             ("linear", "U", "e", "bias")))
-               for s in sv.snaps]
+    refs = sv.model.Reference.of(sv.lay, sv.snaps)
     sv.snaps = None
     worst = {"score_err": 0.0, "topk_short": 0.0, "dead_slots": 0,
              "bad_rows": 0}
@@ -678,13 +639,12 @@ def compare(sv: Served, replies, answered, t0, t_end):
         cands = {i: cat.candidates(replies["sent"][i], replies["recv"][i])
                  for i in mine}
         records = cat.all_records()
-        for p, w in enumerate(weights):
+        for p, ref in enumerate(refs):
             ctxs = sorted({int(replies["ctx"][i]) for i in mine
                            for v in cands[i] if cat.params[v] == p})
             if not ctxs:
                 continue
-            item = w.side(records, item=True)
-            S, A = w.scores(w.side(pool[ctxs], item=False), item)
+            S, A = ref.scores(pool[ctxs], records)
             row = {c: j for j, c in enumerate(ctxs)}
             for i in mine:
                 for v in cands[i]:

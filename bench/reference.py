@@ -1,25 +1,13 @@
-"""The plain reference: DPLR-FwFM corpus scores in float64 NumPy, and the
-comparison of served replies with it.
+"""What the plain reference of every model shares: the catalogue's
+history, the verdict on one reply, and the blocking of a large catalogue.
 
-It imports nothing of the program and takes nothing the program made:
-the layout comes from the configuration file's vocabulary tiers, the
+A model's own float64 reference lives in its module (``bench/models``),
+which, like this file, imports nothing of the program and takes nothing
+the program made: the layout comes from the configuration file, the
 weights are the ones the benchmark drew from the seed, and the item in
-each slot is what the benchmark wrote there.  The model (the paper's
-Proposition 1 with R = U^T diag(e) U - diag(U^T diag(e) U)):
-
-    score = bias + lin_C + lin_I + 0.5 (s_C + t_I + sum_r e_r |P_r + Q_r|^2)
-    P = U_C V_C,  Q = U_I V_I,  s = sum_f d_f |v_f|^2,  d = -diag(U^T e U)
-
-with ``v_f`` the arena row ``offset(f) + id`` and every feature weight 1.
-``|P_r + Q_r|^2`` is expanded to ``|P_r|^2 + 2 P_r.Q_r + |Q_r|^2`` so a
-batch of contexts meets the whole catalogue in one float64 matrix
-product; in float64 the expansion's rounding is some 1e-16 of the terms,
-far below the float32 program's.  The item side is computed in blocks of
-rows, so a catalogue of a million items fits in host memory.
-
-Each score comes with ``absum``: the same expression over the absolute
-value of every term.  Errors are reported as a share of it — the scale
-at which float32 rounding of this computation lives.
+each slot is what the benchmark wrote there.  ``Catalogue`` says which
+item sat in each slot at each version; ``judge`` compares one served
+reply with the reference's scores at one version.
 """
 from __future__ import annotations
 
@@ -27,108 +15,17 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-from bench import traffic
-
 BLOCK = 1 << 15
 THREADS = 4
 
 
-class Layout:
-    """Field vocabularies and arena offsets from a configuration's model
-    block (context fields first, then item fields)."""
-
-    def __init__(self, model: dict):
-        self.v_ctx = traffic.vocabs(model["context_tiers"])
-        self.v_item = traffic.vocabs(model["item_tiers"])
-        self.m_ctx, self.m_item = len(self.v_ctx), len(self.v_item)
-        self.k = int(model["embed_dim"])
-        self.rank = int(model["rank"])
-        starts = np.concatenate([[0], np.cumsum(self.v_ctx + self.v_item)])
-        self.off_ctx = starts[:self.m_ctx].astype(np.int64)
-        self.off_item = starts[self.m_ctx:-1].astype(np.int64)
-        self.total = int(starts[-1])
-        self.rows = -(-self.total // 2048) * 2048   # the arena's padded rows
-
-
-class Side:
-    """float64 one-side terms of rows of ids: projection ``P`` (n, rank,
-    k), its absolute version, ``s`` (the d-term), ``lin``, and their
-    absolute versions."""
-
-    __slots__ = ("P", "Pa", "s", "sa", "lin", "lina")
-
-    def __init__(self, P, Pa, s, sa, lin, lina):
-        self.P, self.Pa, self.s, self.sa = P, Pa, s, sa
-        self.lin, self.lina = lin, lina
-
-
-class Weights:
-    """Host copies of one params snapshot, in float64 where small."""
-
-    def __init__(self, lay: Layout, emb, linear, U, e, bias):
-        self.emb = emb                              # (rows, k) float32
-        self.linear = np.asarray(linear, np.float64)
-        self.U = np.asarray(U, np.float64)
-        self.e = np.asarray(e, np.float64)
-        self.bias = float(bias)
-        self.d = -np.einsum("r,rm,rm->m", self.e, self.U, self.U)
-        self.lay = lay
-
-    def side(self, ids: np.ndarray, item: bool) -> Side:
-        lay = self.lay
-        off = lay.off_item if item else lay.off_ctx
-        cols = slice(lay.m_ctx, None) if item else slice(0, lay.m_ctx)
-        U, d = self.U[:, cols], self.d[cols]
-        n = len(ids)
-        P = np.empty((n, lay.rank, lay.k))
-        Pa = np.empty_like(P)
-        s, sa, lin, lina = (np.empty(n) for _ in range(4))
-        Ua = np.abs(U).astype(np.float32)
-        m, k = U.shape[1], lay.k
-
-        def block(a):
-            rows = (ids[a:a + BLOCK].astype(np.int64) + off).T   # (m, b)
-            b = rows.shape[1]
-            Vf = self.emb[rows].reshape(m, b * k)               # float32
-            V = Vf.astype(np.float64)
-            # sum_m U_rm v_bmk as one (rank, m) x (m, b k) product; the
-            # absolute version only scales errors, so float32 serves it
-            P[a:a + BLOCK] = (U @ V).reshape(lay.rank, b, k).transpose(
-                1, 0, 2)
-            Pa[a:a + BLOCK] = (Ua @ np.abs(Vf)).reshape(
-                lay.rank, b, k).transpose(1, 0, 2)
-            V = V.reshape(m, b, k)
-            sq = np.einsum("mbk,mbk->mb", V, V)
-            s[a:a + BLOCK] = d @ sq
-            sa[a:a + BLOCK] = np.abs(d) @ sq
-            w = self.linear[rows]
-            lin[a:a + BLOCK] = w.sum(0)
-            lina[a:a + BLOCK] = np.abs(w).sum(0)
-
-        with ThreadPoolExecutor(THREADS) as pool:
-            list(pool.map(block, range(0, n, BLOCK)))
-        return Side(P, Pa, s, sa, lin, lina)
-
-    def scores(self, ctx: Side, item: Side):
-        """((contexts, items) scores, same-shape absolute sums)."""
-        e, ea = self.e, np.abs(self.e)
-        n, rho, k = item.P.shape
-
-        def pair(P, Q, w):
-            # sum_r w_r |P_r + Q_r|^2, expanded, for every (context, item)
-            pp = np.einsum("crk,r->c", P * P, w)
-            qq = np.einsum("nrk,r->n", Q * Q, w)
-            cross = (P * w[None, :, None]).reshape(len(P), rho * k) @ \
-                Q.reshape(n, rho * k).T
-            return pp[:, None] + 2.0 * cross + qq[None, :]
-
-        score = (self.bias + ctx.lin[:, None] + item.lin[None, :]
-                 + 0.5 * (ctx.s[:, None] + item.s[None, :]
-                          + pair(ctx.P, item.P, e)))
-        absum = (abs(self.bias) + ctx.lina[:, None] + item.lina[None, :]
-                 + 0.5 * (ctx.sa[:, None] + item.sa[None, :]
-                          + pair(ctx.Pa, item.Pa, ea)))
-        return score, absum
+def blocked(n: int, block) -> None:
+    """Call ``block(a, b)`` over ``[0, n)`` in blocks of ``BLOCK`` rows on
+    ``THREADS`` threads, so a catalogue of a million items fits in host
+    memory; each call writes its own rows of the caller's arrays."""
+    with ThreadPoolExecutor(THREADS) as pool:
+        list(pool.map(lambda a: block(a, min(a + BLOCK, n)),
+                      range(0, n, BLOCK)))
 
 
 class Catalogue:

@@ -14,6 +14,7 @@ from bench.harness import Run
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 MS = 1_000_000
+DPLR = harness.model_module("dplr_fwfm")
 
 
 def _run(trace=None, **kw):
@@ -35,7 +36,7 @@ def test_hand_made_trace():
                  ("_kernel_topk", 6 * MS, 1 * MS), ("copy.3", 9 * MS, 3 * MS),
                  ("early", -5 * MS, 1 * MS)]},
              "host": [("python", "PjitFunction(f)", 4 * MS, 2 * MS)]}
-    got = devtrace.reduce(trace, harness.KERNEL_EVENT)
+    got = devtrace.reduce(trace, DPLR.KERNEL_EVENT)
     assert got["window_s"] == pytest.approx(0.010)
     assert got["busy_s"] == pytest.approx(0.005)
     assert got["kernel_s"] == pytest.approx(0.001)
@@ -55,10 +56,17 @@ def test_hand_made_trace():
 
 def test_work_counts_and_readers():
     rho, k = 3, 16
-    assert work.pair_flops(rho, k) == 155
-    assert work.context_flops(44, rho, k) == 2 * 3 * 44 * 16 + 3 * 44 * 16 \
+    assert DPLR.pair_flops(rho, k) == 155
+    assert DPLR.context_flops(44, rho, k) == 2 * 3 * 44 * 16 + 3 * 44 * 16 \
         + 2 * 44
-    assert work.launch_bytes(8192, 0, 16, rho, k) == 8192 * 50 * 4 + 12
+    config = json.load(open(os.path.join(
+        harness.BENCH, "configs", "dplr-fwfm-auction8k.json")))
+    lay = DPLR.Layout(config["model"])
+    assert (lay.rank, lay.k, lay.query_width) == (rho, k, 44)
+    assert DPLR.launch_bytes(lay, 8192, 0, 16) == 8192 * 50 * 4 + 12
+    assert DPLR.request_flops(lay, 8192) == 8192 * 155 + DPLR.context_flops(
+        44, rho, k)
+    assert DPLR.launch_flops(lay, 8192, 2.5) == 2.5 * 8192 * 155
     least, bound = work.roofline_seconds(1.97e12, 0.0, work.peaks(
         "TPU v5 lite"))
     assert (least, bound) == (pytest.approx(0.01), "flops")
@@ -66,13 +74,15 @@ def test_work_counts_and_readers():
         work.peaks("TPU v9")
     run = _run()
     assert harness.reader("p50_ms")(run) == 2.0
+    assert harness.reader("p50_ms.open")(run) == 2.0
     assert harness.reader("req_per_s")(run) == 50.0
     assert harness.reader("rows_per_dispatch.auction")(run) == 4.0
     assert harness.reader("write_p90_ms")(run) is None
     assert harness.reader("device_idle.auction")(run) is None
     peak = run.peak["bf16_flops_per_s"]
-    assert harness.reader("mfu.auction")(run) == pytest.approx(
-        100 * 1e9 / (2e-3 * peak))
+    for name in ("mfu.auction", "mfu.open"):
+        assert harness.reader(name)(run) == pytest.approx(
+            100 * 1e9 / (2e-3 * peak))
     assert harness.reader("mfu.retrieval")(run) == pytest.approx(
         100 * 1e9 * 50 / peak)
 
@@ -81,7 +91,7 @@ def test_recorded_v5e_trace():
     """30 ms of an auction8k.steady window traced on a TPU v5e: the
     reduction agrees with a recount on a 1 us grid."""
     raw = json.load(open(os.path.join(DATA, "trace_v5e.json")))
-    got = devtrace.reduce(raw, harness.KERNEL_EVENT)
+    got = devtrace.reduce(raw, DPLR.KERNEL_EVENT)
     lo, hi = raw["window"]
     events = raw["device"]["/device:TPU:0"]
     grid = np.zeros((hi - lo) // 1000 + 1, bool)
