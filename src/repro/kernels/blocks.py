@@ -42,6 +42,8 @@ from jax.experimental.pallas import tpu as pltpu
 # Named default tile sizes (retune here, not at call sites).  Values are
 # VMEM-budget choices for the f32 shapes documented in each kernel.
 CORPUS_TILE_N = 2048    # dplr_corpus_score: item (lane) tile of (rho, k, n)
+MIND_TILE_N = 16384     # mind_corpus_score: item (lane) tile of (d, n);
+                        # the largest whose d = 64 slab columns fit VMEM
 ITEM_TILE_N = 1024      # dplr_score_items: item-axis tile of (n, mI, k)
 PAIRWISE_TILE_B = 512   # fwfm_pairwise: example-axis tile of (B, m, k)
 ATTN_TILE = 128         # flash_attention: q/k row tile (MXU lane width)
@@ -56,6 +58,15 @@ ATTN_TILE = 128         # flash_attention: q/k row tile (MXU lane width)
 # block_n = 32768 compiles at every rows in 1..128, and 65536 runs out of
 # VMEM at 1 and 2 rows.
 CORPUS_VMEM_PAIRS = 32768
+
+# Scoped-VMEM rule of the MIND scorer.  A tile holds the (d, block_n) slab
+# columns (double-buffered), the (K x rows, block_n) interest-by-item
+# product and the merge's (rows, block_n) arrays, rows being the query
+# rows padded to 8.  Compiled for the v5e (top-K 16, d 64, K 4, 2^24
+# slots, f32 and bf16 slabs): 16384 compiles at 8 to 64 rows and 32768 at
+# none; at 128 rows 8192 compiles and 16384 does not.  So rows x block_n
+# <= 2^20, under MIND_TILE_N.
+MIND_VMEM_PAIRS = 1 << 20
 
 # Bounded log of tile clamps (requested > axis length).  Appended at
 # trace time by clamp_tile; drained by the autotuner / benchmarks.
@@ -100,6 +111,15 @@ def corpus_vmem_tile(block_n: int, rows: int) -> int:
     the query rows one launch scores: Bq, or S * Bq for the multi-segment
     kernel."""
     cap = max(128, 1 << (max(CORPUS_VMEM_PAIRS // max(rows, 1), 1)
+                         .bit_length() - 1))
+    return min(block_n, cap)
+
+
+def mind_vmem_tile(block_n: int, rows: int) -> int:
+    """The largest power-of-two MIND tile <= ``block_n`` whose ``rows x
+    tile`` pairs fit ``MIND_VMEM_PAIRS`` (never below 128, the lane
+    count).  ``rows`` is a launch's query rows padded to a multiple of 8."""
+    cap = max(128, 1 << (max(MIND_VMEM_PAIRS // max(rows, 1), 1)
                          .bit_length() - 1))
     return min(block_n, cap)
 

@@ -35,10 +35,12 @@ Two output modes:
     grid steps (constant index_map => the block stays resident in VMEM);
     each step merges its tile's scores into the running (values, indices)
     pair, so only ``(Bq, K)`` floats + ints ever leave the scorer.  Mosaic
-    has no ``top_k`` lowering, so the merge (``_merge_topk``) is K rounds
-    of row max + lowest-position select over ``[running, tile]`` — the
-    exact ``lax.top_k`` contract on that concat: rows best-first, ties to
-    the lowest position (running entries first, then the tile in order).
+    has no ``top_k`` lowering, so the merge
+    (``topk.merge_running_topk``, shared with ``mind_corpus_score``) is K
+    rounds of row max + lowest-position select over ``[running, tile]`` —
+    the exact ``lax.top_k`` contract on that concat: rows best-first, ties
+    to the lowest position (running entries first, then the tile in
+    order).
 
 Validity mask: the serving corpus is a capacity-padded MUTABLE slab
 (``repro.serving.corpus``), so the kernel takes an optional ``valid`` (n,)
@@ -87,6 +89,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import blocks
+from repro.kernels.topk import merge_running_topk
 
 NEG_INF = -1e30
 
@@ -118,48 +121,6 @@ def _kernel_full(q_ref, a_ref, e_ref, pc_ref, ac_ref, m_ref, out_ref, *,
     out_ref[...] = _tile_scores(
         q_ref[...], a_ref[...], e_ref[...], pc_ref[...], ac_ref[...],
         m_ref[...], acc_dtype)
-
-
-def _merge_topk(val_ref, idx_ref, scores, labels):
-    """Merge one tile's ``(Bq, block_n)`` scores (with their int32 index
-    labels) into the running ``(Bq, K)`` top-K held in ``val_ref`` /
-    ``idx_ref``: ``lax.top_k`` over the concat ``[running, tile]``, built
-    from primitives Mosaic lowers.  Round ``r`` takes each row's max, picks
-    the LOWEST concat position holding it (running entries first, then
-    the tile's rows in order — the top_k tie rule), writes it to column
-    ``r`` and retires it to ``-inf`` (below every live score and below
-    the NEG_INF dead-slot sentinel, so a retired entry is never re-picked
-    while untaken candidates remain)."""
-    run_v, run_i = val_ref[...], idx_ref[...]
-    K = run_v.shape[1]
-    pos_r = jax.lax.broadcasted_iota(jnp.int32, run_v.shape, 1)
-    pos_t = K + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    past = K + scores.shape[1]               # one past the last position
-    lowest = jnp.iinfo(jnp.int32).min
-
-    def row_max(a, b):
-        return jnp.maximum(jnp.max(a, axis=1, keepdims=True),
-                           jnp.max(b, axis=1, keepdims=True))
-
-    def pick(r, carry):
-        cand_r, cand_t, out_v, out_i = carry
-        m = row_max(cand_r, cand_t)
-        p = jnp.minimum(
-            jnp.min(jnp.where(cand_r == m, pos_r, past), axis=1,
-                    keepdims=True),
-            jnp.min(jnp.where(cand_t == m, pos_t, past), axis=1,
-                    keepdims=True))
-        lab = row_max(jnp.where(pos_r == p, run_i, lowest),
-                      jnp.where(pos_t == p, labels, lowest))
-        col = pos_r == r
-        return (jnp.where(pos_r == p, -jnp.inf, cand_r),
-                jnp.where(pos_t == p, -jnp.inf, cand_t),
-                jnp.where(col, m, out_v), jnp.where(col, lab, out_i))
-
-    _, _, top_v, top_i = jax.lax.fori_loop(
-        0, K, pick, (run_v, scores, run_v, run_i))
-    val_ref[...] = top_v
-    idx_ref[...] = top_i
 
 
 def _kernel_topk(q_ref, a_ref, e_ref, pc_ref, ac_ref, m_ref, off_ref,
@@ -198,7 +159,7 @@ def _kernel_topk(q_ref, a_ref, e_ref, pc_ref, ac_ref, m_ref, off_ref,
         scores = jnp.where(own, scores, NEG_INF)
     labels = off_ref[0] + index_stride * (
         row_base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-    _merge_topk(val_ref, idx_ref, scores, labels)
+    merge_running_topk(val_ref, idx_ref, scores, labels)
 
 
 def _item_rows(Q_I, a_I, valid, pad):

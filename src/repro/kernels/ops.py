@@ -24,6 +24,7 @@ from repro.kernels import dplr_score as _dplr
 from repro.kernels import embedding_bag as _bag
 from repro.kernels import flash_attention as _flash
 from repro.kernels import fwfm_interaction as _fwfm
+from repro.kernels import mind_corpus_score as _mind
 
 
 def _on_tpu() -> bool:
@@ -87,6 +88,20 @@ def dplr_corpus_score_multi(Q_parts, a_parts, valid_parts, e, P_C, a_C, *,
         e, P_C, a_C, topk=topk, block_n=block_n, interpret=interp,
         index_offset=index_offset, index_stride=index_stride,
         acc_dtype=acc_dtype)
+
+
+def mind_corpus_score(E, U, live, valid=None, *, topk=None,
+                      block_n: int | None = None,
+                      interpret: bool | None = None):
+    """MIND's max-over-interests scorer; ``block_n=None`` takes the
+    largest tile the VMEM rule allows for this launch's query rows
+    (``blocks.mind_vmem_tile``)."""
+    interp = (not _on_tpu()) if interpret is None else interpret
+    if block_n is None:
+        rows = -(-U.shape[0] // 8) * 8
+        block_n = blocks.mind_vmem_tile(blocks.MIND_TILE_N, rows)
+    return _mind.mind_corpus_score(E, U, live, valid, topk=topk,
+                                   block_n=block_n, interpret=interp)
 
 
 def fwfm_pairwise(V, R, *, block_b: int = blocks.PAIRWISE_TILE_B,

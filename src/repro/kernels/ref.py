@@ -58,6 +58,25 @@ def dplr_corpus_multi_topk_ref(Q_parts, a_parts, valid_parts, e, P_C, a_C,
     return jnp.stack(vals), jnp.stack(idx)
 
 
+def mind_corpus_score_ref(E, U, live, valid=None):
+    """(Bq, n) max-over-live-interests scores ``max_k <U[q, k], E[i]>``
+    at full f32 precision, dead slots pinned to NEG_INF."""
+    from repro.kernels.dplr_corpus_score import NEG_INF
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("qkd,nd->qkn", U.astype(jnp.float32),
+                       E.astype(jnp.float32))
+    s = jnp.where(jnp.asarray(live)[..., None], s, NEG_INF).max(axis=1)
+    if valid is not None:
+        s = jnp.where(jnp.asarray(valid)[None, :], s, NEG_INF)
+    return s
+
+
+def mind_corpus_topk_ref(E, U, live, topk, valid=None):
+    """``lax.top_k`` of ``mind_corpus_score_ref``: ((Bq, K) scores,
+    (Bq, K) slots), ties to the lowest slot."""
+    return jax.lax.top_k(mind_corpus_score_ref(E, U, live, valid), topk)
+
+
 def fwfm_pairwise_ref(V, R):
     G = jnp.einsum("bik,bjk->bij", V, V)
     return 0.5 * jnp.einsum("bij,ij->b", G, R)
@@ -99,6 +118,7 @@ ORACLES = {
     "dplr_score_items": (dplr_score_items_ref,),
     "dplr_corpus_score": (dplr_corpus_score_ref, dplr_corpus_topk_ref),
     "dplr_corpus_score_multi": (dplr_corpus_multi_topk_ref,),
+    "mind_corpus_score": (mind_corpus_score_ref, mind_corpus_topk_ref),
     "fwfm_pairwise": (fwfm_pairwise_ref,),
     "embedding_bag": (embedding_bag_ref,),
     "flash_attention": (flash_attention_ref,),

@@ -254,6 +254,7 @@ def _recsys_train_batch(arch_name, cfg, B):
     lay = cfg.layout
     if arch_name == "mind":
         return {
+            "profile_ids": _sds((B, lay.n_context), jnp.int32),
             "hist_ids": _sds((B, cfg.seq_len), jnp.int32),
             "hist_mask": _sds((B, cfg.seq_len), jnp.float32),
             "target_id": _sds((B,), jnp.int32),
@@ -284,8 +285,8 @@ def _recsys_rank_query(arch_name, cfg, n_queries, n_items):
         q["hist_ids"] = _sds((n_queries, cfg.seq_len), jnp.int32)
         q["hist_mask"] = _sds((n_queries, cfg.seq_len), jnp.float32)
     if arch_name == "mind":
-        q.pop("context_ids"), q.pop("context_weights")
-        q.pop("item_weights")
+        q["profile_ids"] = q.pop("context_ids")
+        q.pop("context_weights"), q.pop("item_weights")
     return q
 
 

@@ -59,7 +59,11 @@ def build_recsys_trainer(arch_name: str, cfg, batch_size: int, seed: int):
         if arch_name == "mind":
             rng = np.random.default_rng((seed, 3, step))
             item_vocab = cfg.layout.fields[-1].vocab_size
+            profile = [f.vocab_size for f in cfg.layout.fields[:-1]]
             return {
+                "profile_ids": np.stack(
+                    [rng.integers(0, v, batch_size) for v in profile],
+                    axis=1).astype(np.int32),
                 "hist_ids": rng.integers(0, item_vocab,
                                          (batch_size, cfg.seq_len)).astype(np.int32),
                 "hist_mask": np.ones((batch_size, cfg.seq_len), np.float32),

@@ -30,9 +30,12 @@ compute, per-tenant state, shared request routing:
     requests, and a per-tenant writer barrier — tenant-A churn never
     drains tenant-B's in-flight reads.
 
-    corpus.py   - ItemCorpusCache + build_corpus_cache + corpus_rows +
-                  masked_slab_scores (the precompute and scoring math;
-                  slab/mask invariants documented here)
+    corpus.py   - the serving contract a model supplies (slab rows, query
+                  state, score function; ``scorer_for`` picks it from
+                  the config): DPLRScorer (ItemCorpusCache +
+                  build_corpus_cache + corpus_rows + masked_slab_scores,
+                  slab/mask invariants documented here) and MINDScorer
+                  (MINDSlab, the user tower, max-over-interests scores)
     runtime.py  - ScorerRuntime (shared jitted dispatch + trace cache,
                   warmup grid, host-side churn bucketing/grouping)
     engine.py   - CorpusState / CorpusRankingEngine (per-tenant slab,

@@ -1,4 +1,31 @@
-"""Item-corpus precomputation for DPLR-FwFM serving.
+"""The serving contract of a model, and DPLR-FwFM's and MIND's item slabs.
+
+A served model supplies three things, through a scorer object that
+``ScorerRuntime`` picks from the config it is given (``scorer_for``):
+
+  * slab rows from item ids — ``rows`` (one churn delta), ``build`` (the
+    whole slab, at refresh) — held in a NamedTuple cache whose every leaf
+    has the slab ``capacity`` as its leading axis and whose last leaf is
+    the ``valid`` mask, so the runtime writes, drops and grows any model's
+    slab the same way;
+  * a query state from a batch of query rows — ``query``;
+  * scores — ``scores`` (the jnp form, ``(Bq, capacity)``, dead slots
+    ``NEG_INF``), ``kernel`` (the Pallas launch, fused top-K) and
+    ``kernel_multi`` (S tenants' micro-batches in one program).
+
+Plus ``query_width`` (slots of one query row), ``vectors`` (the query
+vectors a batch scans and how many of them are live, counted on the host)
+and ``span`` (a host span around the launch, or None).
+
+``DPLRScorer`` is DPLR-FwFM: the slab is ``ItemCorpusCache`` and the query
+state the context cache, below.  ``MINDScorer`` is MIND
+(``models/recsys/mind.py``): the slab is one item-embedding row per slot
+(``MINDSlab``), the query state the user tower's interest vectors, and a
+score the max over live interests of an inner product
+(``kernels/mind_corpus_score.py``).
+
+Item-corpus precomputation for DPLR-FwFM serving
+------------------------------------------------
 
 The paper's Algorithm 1 caches the CONTEXT side per query; this module
 extends the same caching argument to the ITEM side, across queries.  The
@@ -57,9 +84,12 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
+from repro.core import ranking as rk
 from repro.core.dplr import DPLRParams, dplr_diagonal
 from repro.embedding.bag import (
     item_arena_ids,
@@ -70,6 +100,7 @@ from repro.embedding.bag import (
 # kernel so the jnp and kernel paths return bit-identical scores for
 # invalid slots.
 from repro.kernels.dplr_corpus_score import NEG_INF
+from repro.models.recsys import mind
 
 
 def serving_precision():
@@ -184,3 +215,172 @@ def build_corpus_cache(params: dict, cfg, item_ids: jax.Array,
                                   take_fn=take_fn)
     return ItemCorpusCache(Q_I=Q_I, t_I=t_I, lin_I=lin_I,
                            valid=jnp.asarray(valid))
+
+
+# -- the models on the serving contract -------------------------------------
+
+def scorer_for(cfg):
+    """The serving scorer of a model config: MIND for a ``MINDConfig``,
+    else DPLR-FwFM (which requires ``interaction='dplr'``)."""
+    if isinstance(cfg, mind.MINDConfig):
+        return MINDScorer(cfg)
+    return DPLRScorer(cfg)
+
+
+class DPLRScorer:
+    """DPLR-FwFM on the serving contract: slab rows ``(Q_I, t_I, lin_I)``,
+    the per-query context cache ``(P_C, s_C, lin_C)`` as the query state,
+    and the fused square-sum scorer (``kernels/dplr_corpus_score.py``)."""
+
+    span = None
+
+    def __init__(self, cfg):
+        if cfg.interaction != "dplr":
+            raise ValueError("ScorerRuntime requires interaction='dplr'")
+        self.cfg = cfg
+        self.query_width = len(cfg.layout.slots_of("context"))
+
+    def signature(self) -> tuple:
+        lay = self.cfg.layout
+        return (lay.n_context, lay.n_item, self.cfg.embed_dim, self.cfg.rank)
+
+    def rows(self, params, ids, w):
+        return corpus_rows(params, self.cfg, ids, w)
+
+    def build(self, params, ids, w, valid):
+        return build_corpus_cache(params, self.cfg, ids, w, valid=valid)
+
+    def query(self, params, ctx_ids, ctx_w):
+        """Per-query context cache: P_C (Bq, rho, k), s_C (Bq,), lin_C (Bq,)."""
+        from repro.models.recsys.fwfm import context_inputs
+        with serving_precision():
+            V_C, lin_C = context_inputs(params, self.cfg, ctx_ids, ctx_w)
+            p = DPLRParams(params["U"], params["e"])
+            ctx = rk.dplr_context_cache(p, V_C, self.cfg.layout.n_context)
+        return ctx.P_C, ctx.s_C, lin_C
+
+    def scores(self, params, cache, q):
+        # direct fused form — same reduction order as rank_items, so the
+        # corpus-cached path is float32-epsilon-close to the per-query
+        # path; shared with the sharded runtime so the two are
+        # bit-identical per slot.
+        return masked_slab_scores(params, cache.Q_I, cache.t_I, cache.lin_I,
+                                  cache.valid, *q)
+
+    def kernel(self, params, cache, q, K, block_n):
+        from repro.kernels import ops as kops
+        P_C, s_C, lin_C = q
+        a_C = params["bias"] + lin_C + 0.5 * s_C
+        return kops.dplr_corpus_score(cache.Q_I, cache.a_I, params["e"],
+                                      P_C, a_C, valid=cache.valid, topk=K,
+                                      block_n=block_n)
+
+    def kernel_multi(self, params_parts, cache_parts, q_parts, K, block_n):
+        """ONE tenant-segmented kernel launch
+        (``kernels.dplr_corpus_score_multi``) covers every segment's slab
+        — the per-segment running top-K never mixes tenants' slots."""
+        from repro.kernels import ops as kops
+        pcs, acs, es = [], [], []
+        for params, (P_C, s_C, lin_C) in zip(params_parts, q_parts):
+            pcs.append(P_C)
+            acs.append(params["bias"] + lin_C + 0.5 * s_C)
+            es.append(params["e"])
+        return kops.dplr_corpus_score_multi(
+            tuple(c.Q_I for c in cache_parts),
+            tuple(c.a_I for c in cache_parts),
+            tuple(c.valid for c in cache_parts),
+            jnp.stack(es), jnp.stack(pcs), jnp.stack(acs),
+            topk=K, block_n=block_n)
+
+    @staticmethod
+    def vectors(ctx_ids) -> tuple[int, int]:
+        """One query vector per row, always live."""
+        return len(ctx_ids), len(ctx_ids)
+
+
+def take_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
+    """Rows ``ids`` of a ``(rows, d)`` table, gathered through its
+    ``(rows, d / 16, 16)`` view when 16 divides a wider ``d``.  The TPU
+    keeps an ``(rows, 64)`` f32 array items-minor and, asked for whole
+    rows of it, first relayouts the whole table (a 2x padded copy: 6.4 GB
+    for MIND's arena of 12.5M rows, on every launch); the 16-wide view
+    gathers the same bytes in place (v5e compile: no temporary)."""
+    n, d = table.shape
+    if d <= 16 or d % 16:
+        return jnp.take(table, ids, axis=0)
+    return jnp.take(table.reshape(n, d // 16, 16), ids, axis=0).reshape(
+        *ids.shape, d)
+
+
+class MINDSlab(NamedTuple):
+    """MIND's item slab: each slot's item-embedding row (the arena row of
+    its item id) and the liveness mask.  The TPU stores ``E`` with the
+    slot axis minor, so the kernel reads it transposed at no copy."""
+
+    E: jax.Array       # (cap, d) item embedding rows
+    valid: jax.Array   # (cap,)   bool liveness mask
+
+    @property
+    def capacity(self) -> int:
+        return self.E.shape[0]
+
+
+class MINDScorer:
+    """MIND on the serving contract.  A query row is the ``m_p`` profile
+    ids followed by the ``seq_len`` history item ids, a history id below
+    0 marking padding (its weight is not read: padding rides in the
+    ids).  The query state is the user tower's ``(Bq, K, d)`` interest
+    vectors and ``(Bq, K)`` live mask, computed on the device in the
+    launch's own program; an item row is one id (weights are not read
+    on either side)."""
+
+    span = "engine.mind_launch"
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.n_profile = cfg.layout.n_context
+        self.query_width = self.n_profile + cfg.seq_len
+        self.item_offset = int(cfg.layout.field_offsets[cfg.layout.n_context])
+
+    def signature(self) -> tuple:
+        c = self.cfg
+        return ("mind", c.layout.n_context, c.embed_dim, c.n_interests,
+                c.capsule_iters, c.seq_len, c.hidden)
+
+    def rows(self, params, ids, w):
+        return (take_rows(params["embedding"], ids[:, 0] + self.item_offset),)
+
+    def build(self, params, ids, w, valid):
+        return MINDSlab(self.rows(params, ids, w)[0], valid)
+
+    def query(self, params, ids, w):
+        hist = ids[:, self.n_profile:]
+        mask = (hist >= 0).astype(params["S"].dtype)
+        return mind.user_tower(params, self.cfg, ids[:, :self.n_profile],
+                               jnp.maximum(hist, 0), mask, take_fn=take_rows)
+
+    def scores(self, params, cache, q):
+        U, live = q
+        with serving_precision():
+            s = jnp.einsum("qkd,nd->qkn", U, cache.E)
+        s = jnp.where(live[..., None], s, NEG_INF).max(axis=1)
+        return jnp.where(cache.valid[None, :], s, NEG_INF)
+
+    def kernel(self, params, cache, q, K, block_n):
+        from repro.kernels import ops as kops
+        U, live = q
+        return kops.mind_corpus_score(cache.E, U, live, cache.valid, topk=K,
+                                      block_n=block_n)
+
+    def kernel_multi(self, params_parts, cache_parts, q_parts, K, block_n):
+        """S launches of the kernel in one program, one per segment."""
+        vals, idx = zip(*(self.kernel(p, c, q, K, block_n) for p, c, q in
+                          zip(params_parts, cache_parts, q_parts)))
+        return jnp.stack(vals), jnp.stack(idx)
+
+    def vectors(self, ids) -> tuple[int, int]:
+        """(interest vectors scanned, those live) for a batch of query
+        rows: K per row, of which K'_u live."""
+        K = self.cfg.n_interests
+        lengths = (np.asarray(ids)[:, self.n_profile:] >= 0).sum(axis=1)
+        return len(lengths) * K, int(mind.interest_counts(lengths, K).sum())

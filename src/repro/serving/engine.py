@@ -88,11 +88,11 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.serving.corpus import ItemCorpusCache, next_pow2
+from repro.serving.corpus import next_pow2
 from repro.serving.errors import NotReady, RefreshFailed
 from repro.serving.runtime import ScorerRuntime
 from repro.serving.sanitize import scoring_guard
-from repro.serving.telemetry import span
+from repro.serving.telemetry import OFF, span
 
 
 class CorpusState:
@@ -168,7 +168,7 @@ class CorpusState:
         self._n_free = self.capacity - n0
 
         self.params: dict | None = None
-        self.cache: ItemCorpusCache | None = None
+        self.cache = None                  # the model's slab (NamedTuple)
         self.model_step: int | None = None
         self._last_polled_sig: tuple | None = None
         self.refresh_count = 0
@@ -201,6 +201,17 @@ class CorpusState:
     @property
     def _wdtype(self):
         return self.runtime.wdtype
+
+    @property
+    def query_width(self) -> int:
+        """Slots of one query row (the frontend checks submits by it)."""
+        return self.runtime.query_width
+
+    def query_vectors(self, context_ids) -> tuple[int, int]:
+        """(query vectors, live ones) a launch of these query rows scans,
+        counted on the host: one per row for DPLR-FwFM; K per row for
+        MIND, of which the user's K'_u are live."""
+        return self.runtime.model.vectors(context_ids)
 
     @property
     def trace_count(self) -> int:
@@ -438,22 +449,11 @@ class CorpusState:
         self._n_free += extra
         self.capacity = new
         if self.cache is not None:
-            if self.mesh is None:
-                self.cache = ItemCorpusCache(
-                    Q_I=jnp.pad(self.cache.Q_I, ((0, extra), (0, 0), (0, 0))),
-                    t_I=jnp.pad(self.cache.t_I, (0, extra)),
-                    lin_I=jnp.pad(self.cache.lin_I, (0, extra)),
-                    valid=jnp.pad(self.cache.valid, (0, extra)),
-                )
-            else:
-                ex = extra // self._D        # per-shard local rows added
-                self.cache = ItemCorpusCache(
-                    Q_I=jnp.pad(self.cache.Q_I,
-                                ((0, ex), (0, 0), (0, 0), (0, 0))),
-                    t_I=jnp.pad(self.cache.t_I, ((0, ex), (0, 0))),
-                    lin_I=jnp.pad(self.cache.lin_I, ((0, ex), (0, 0))),
-                    valid=jnp.pad(self.cache.valid, ((0, ex), (0, 0))),
-                )
+            # every slab leaf has the (local) slot axis first: pad it
+            ex = extra // self._D        # per-shard local rows added
+            self.cache = jax.tree.map(
+                lambda a: jnp.pad(a, ((0, ex),) + ((0, 0),) * (a.ndim - 1)),
+                self.cache)
 
     # -- corpus/model lifecycle --------------------------------------------
 
@@ -577,7 +577,8 @@ class CorpusState:
         with scoring_guard():
             return self.runtime.score(self.params, self.cache, ids, w)
 
-    def topk(self, context_ids, K: int, context_weights=None):
+    def topk(self, context_ids, K: int, context_weights=None, *,
+             batch: int | None = None):
         """((Bq, K) scores, (Bq, K) int32 corpus slot indices) — only the
         winners leave the scorer, not the (Bq, capacity) logit matrix.
         Masked: a dead slot can never be returned (K is checked against the
@@ -590,22 +591,27 @@ class CorpusState:
         dispatch-per-batch design rests on.  Non-blocking, like
         ``score``.  K is static under jit: each distinct K traces once
         on the shared runtime (the frontend quantizes K to power-of-two
-        buckets for exactly this reason)."""
+        buckets for exactly this reason).  ``batch`` (the frontend's
+        dispatch number) labels the model's launch span, where it has
+        one (MIND: ``engine.mind_launch``)."""
         self._require_ready()
         if not 0 < K <= self.n_items:
             raise ValueError(
                 f"topk K={K} out of range for corpus of {self.n_items} "
                 f"live items")
-        ids, w = self._ctx_arrays(context_ids, context_weights)
-        if self.use_pallas_kernel and not self.kernel_degraded:
-            out = _kernel_dispatch(
-                (self,), (K, ids.shape),
-                lambda: self.runtime.kernel_score(self.params, self.cache,
-                                                  ids, w, K=K))
-            if out is not None:
-                return out
-        with scoring_guard():
-            return self.runtime.topk(self.params, self.cache, ids, w, K=K)
+        name = self.runtime.model.span
+        with span(name, batch=batch) if name else OFF:
+            ids, w = self._ctx_arrays(context_ids, context_weights)
+            if self.use_pallas_kernel and not self.kernel_degraded:
+                out = _kernel_dispatch(
+                    (self,), (K, ids.shape),
+                    lambda: self.runtime.kernel_score(
+                        self.params, self.cache, ids, w, K=K))
+                if out is not None:
+                    return out
+            with scoring_guard():
+                return self.runtime.topk(self.params, self.cache, ids, w,
+                                         K=K)
 
     def warmup_grid(self, context_ids, context_weights=None, *,
                     max_batch: int = 16, max_k: int = 16) -> int:

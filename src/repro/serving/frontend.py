@@ -412,7 +412,7 @@ class _TenantLane:
         self.engine = engine
         self.heap: list = []                      # (deadline|inf, seq, req)
         self.arrivals: collections.deque = collections.deque()  # FIFO view
-        self.n_ctx = len(engine.cfg.layout.slots_of("context"))
+        self.n_ctx = engine.query_width
         self.stats = {"submitted": 0, "completed": 0, "shed": 0,
                       "failed": 0, "trips": 0, "quota_deferred": 0}
         self.breaker = "closed"                   # closed|open|half_open
@@ -601,7 +601,8 @@ class QueryFrontend:
                       "retries": 0, "degraded": 0, "clamped": 0,
                       "pump_restarts": 0, "pump_errors": 0,
                       "autoscales": 0, "fused_dispatches": 0,
-                      "fused_segments": 0}
+                      "fused_segments": 0, "scan_vectors": 0,
+                      "live_vectors": 0}
         self.last_pump_error: BaseException | None = None
         if hasattr(engines, "topk"):         # single engine, classic API
             engines = {"default": engines}
@@ -800,7 +801,7 @@ class QueryFrontend:
             lane.breaker = "closed"
             lane.opened_at = None
 
-    def _launch(self, lane, ctx, w, k_pad):
+    def _launch(self, lane, ctx, w, k_pad, batch=None):
         """Dispatch ONE assembled micro-batch with bounded retry: every
         attempt re-dispatches the identical (ctx, w, k_pad) — same shape
         bucket (no retrace), same rows (a reply that eventually succeeds
@@ -812,7 +813,7 @@ class QueryFrontend:
             try:
                 if self._injector is not None:
                     self._injector.check("dispatch")
-                return lane.engine.topk(ctx, k_pad, w)
+                return lane.engine.topk(ctx, k_pad, w, batch=batch)
             except Exception as e:            # noqa: BLE001 — typed below
                 if i + 1 >= attempts:
                     raise DispatchFailed(
@@ -1194,7 +1195,7 @@ class QueryFrontend:
                 # without blocking — the device scores while the host
                 # assembles the next micro-batch (the overlap this
                 # frontend exists for)
-                vals, idx = self._launch(lane, ctx, w, k_pad)
+                vals, idx = self._launch(lane, ctx, w, k_pad, batch)
         except DispatchFailed as e:
             for r in live:
                 self.stats["failed"] += 1
@@ -1204,13 +1205,21 @@ class QueryFrontend:
             return
         t_launch = time.perf_counter_ns()
         self._breaker_success(lane)
-        self.stats["dispatches"] += 1
-        self.stats["dispatched_rows"] += bq
-        self.stats["padded_rows"] += bq - len(live)
+        self._count_launch(lane, ctx, bq, len(live))
         self._window.append(_InFlight(live, vals, idx, lane.name,
                                       ctx, w, k_pad, batch, t_launch))
         while len(self._window) > self.inflight:
             self._resolve_oldest()
+
+    def _count_launch(self, lane, ctx, bq: int, n_live: int) -> None:
+        """Count one launched micro-batch: its rows, and the query
+        vectors its scan carries (``CorpusState.query_vectors``)."""
+        self.stats["dispatches"] += 1
+        self.stats["dispatched_rows"] += bq
+        self.stats["padded_rows"] += bq - n_live
+        scan, live = lane.engine.query_vectors(ctx)
+        self.stats["scan_vectors"] += scan
+        self.stats["live_vectors"] += live
 
     def _dispatch_group(self, pairs, now: float) -> None:
         """Launch a ``_collect_group`` group as ONE fused dispatch:
@@ -1277,9 +1286,7 @@ class QueryFrontend:
         self.stats["fused_segments"] += len(live_pairs)
         for seg, (lane, live) in enumerate(live_pairs):
             self._breaker_success(lane)
-            self.stats["dispatches"] += 1
-            self.stats["dispatched_rows"] += bq
-            self.stats["padded_rows"] += bq - len(live)
+            self._count_launch(lane, rows[seg][0], bq, len(live))
             self._window.append(_InFlight(live, None, None, lane.name,
                                           rows[seg][0], rows[seg][1],
                                           k_pad, batch, t_launch,

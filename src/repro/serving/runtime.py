@@ -3,7 +3,11 @@
 ``ScorerRuntime`` owns everything about scoring that does NOT depend on
 which corpus is being scored: the jitted/Pallas dispatch functions, the
 mesh / ``shard_map`` wiring, kernel selection, and the (Bq, K,
-capacity-bucket) warmup grid.  It is keyed purely by shape+dtype — its
+capacity-bucket) warmup grid.  What is model-specific — the slab rows,
+the query state, the score function and its kernel — comes from the
+scorer the runtime picks from its config (``corpus.scorer_for``:
+DPLR-FwFM, or MIND for a ``MINDConfig``; the sharded slab serves
+DPLR-FwFM only).  It is keyed purely by shape+dtype — its
 jit caches are a function of ``(cfg, mesh, kernel choice)`` plus the
 SHAPES of the arrays that flow through them — so **T tenants share one
 trace cache**: a second ``CorpusState`` whose slab capacity (and context
@@ -20,7 +24,7 @@ Layering (see docs/multitenant.md):
 Shapes and dtypes (one runtime, any number of tenants):
 
     score(params, cache, ctx_ids, ctx_w)        -> (Bq, capacity) cfg.dtype
-        ctx_ids (Bq, m_C_slots) int32, ctx_w matching float
+        ctx_ids (Bq, query_width) int32 query rows, ctx_w matching float
     topk(params, cache, ctx_ids, ctx_w, K=K)    -> ((Bq, K) cfg.dtype,
                                                     (Bq, K) int32)  K static
     multi_topk(params_parts, cache_parts, ctx_ids, ctx_w, K=K)
@@ -30,9 +34,10 @@ Shapes and dtypes (one runtime, any number of tenants):
         ``topk`` calls.  Keyed on the segment-count bucket S (a tuple
         length is part of the jit pytree structure), so the frontend's
         packing adds zero retraces beyond its warmed S buckets.
-    build(params, slab_ids, slab_w, valid)      -> ItemCorpusCache
-    write_rows(params, cache, slots, ids, w)    -> ItemCorpusCache (host API)
-    drop_rows(cache, slots)                     -> ItemCorpusCache (host API)
+    build(params, slab_ids, slab_w, valid)      -> the model's slab cache
+                                                   (DPLR: ItemCorpusCache)
+    write_rows(params, cache, slots, ids, w)    -> slab cache (host API)
+    drop_rows(cache, slots)                     -> slab cache (host API)
 
 All device entry points are NON-blocking under JAX async dispatch (they
 return device arrays; reading a result blocks).  ``write_rows`` /
@@ -53,16 +58,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import ranking as rk
-from repro.core.dplr import DPLRParams
-from repro.serving.corpus import (
-    ItemCorpusCache,
-    build_corpus_cache,
-    corpus_rows,
-    masked_slab_scores,
-    next_pow2,
-    serving_precision,
-)
+from repro.serving.corpus import DPLRScorer, next_pow2, scorer_for
 
 
 class ScorerRuntime:
@@ -70,18 +66,19 @@ class ScorerRuntime:
 
     Parameters
     ----------
-    cfg : FwFMConfig
-        Model config (``interaction='dplr'`` required).  ``cfg.dtype`` is
-        the serving dtype: context weights default to it and scores carry
-        it.
+    cfg : FwFMConfig | MINDConfig
+        Model config; picks the scorer (``corpus.scorer_for``: an FwFM
+        config needs ``interaction='dplr'``).  ``cfg.dtype`` is the
+        serving dtype: query weights default to it and scores carry it.
     mesh : jax.sharding.Mesh | None
         When set, caches are stored in the physical ``(capacity/D, D,
         ...)`` striped layout of ``repro.serving.sharded`` and every
         dispatch runs through ``shard_map``; ``None`` is the single-device
         D=1 layout.
     use_pallas_kernel : bool
-        Score through ``kernels.ops.dplr_corpus_score`` (one HBM pass,
-        fused running top-K) instead of the fused-jnp form.
+        Score through the model's Pallas kernel
+        (``kernels.ops.dplr_corpus_score`` or ``mind_corpus_score``: one
+        HBM pass, fused running top-K) instead of the fused-jnp form.
     block_n : int | None
         Pallas kernel corpus-block size.  ``None`` (default) resolves
         through the autotuner registry (``kernels.blocks.corpus_tile``)
@@ -91,8 +88,9 @@ class ScorerRuntime:
 
     def __init__(self, cfg, *, mesh=None, use_pallas_kernel: bool = False,
                  block_n: int | None = None):
-        if cfg.interaction != "dplr":
-            raise ValueError("ScorerRuntime requires interaction='dplr'")
+        self.model = scorer_for(cfg)
+        if mesh is not None and not isinstance(self.model, DPLRScorer):
+            raise ValueError("the sharded slab serves DPLR-FwFM only")
         self.cfg = cfg
         self.wdtype = cfg.dtype   # weights follow the serving dtype — a
         # stray f32 default here silently promotes the whole bf16 path.
@@ -141,58 +139,50 @@ class ScorerRuntime:
         return self._D
 
     @property
+    def query_width(self) -> int:
+        """Slots of one query row."""
+        return self.model.query_width
+
+    @property
     def signature(self) -> tuple:
         """The shape+dtype key this runtime's trace cache is a function
         of (beyond the per-call array shapes): two ``CorpusState``s built
         on runtimes with equal signatures AND equal capacity reach the
         exact same traces."""
-        lay = self.cfg.layout
-        return (lay.n_context, lay.n_item, self.cfg.embed_dim,
-                self.cfg.rank, str(jnp.dtype(self.wdtype)), self._D,
-                self.use_pallas_kernel, self.block_n)
+        return (*self.model.signature(), str(jnp.dtype(self.wdtype)),
+                self._D, self.use_pallas_kernel, self.block_n)
 
     # -- jitted bodies (single-device) --------------------------------------
 
     def _build_impl(self, params, slab_ids, slab_w, valid):
-        return build_corpus_cache(params, self.cfg, slab_ids, slab_w,
-                                  valid=valid)
+        return self.model.build(params, slab_ids, slab_w, valid)
 
     def _rows_impl(self, params, ids, w):
-        return corpus_rows(params, self.cfg, ids, w)
+        return self.model.rows(params, ids, w)
 
-    def _write_impl(self, cache, Q, t, lin, idx):
-        """Scatter Δn precomputed rows into the slab and mark them live.
-        ``idx`` is bucket-padded with ``capacity`` (out of range =>
-        dropped), so one trace serves every Δn in the bucket."""
-        return ItemCorpusCache(
-            Q_I=cache.Q_I.at[idx].set(Q, mode="drop"),
-            t_I=cache.t_I.at[idx].set(t, mode="drop"),
-            lin_I=cache.lin_I.at[idx].set(lin, mode="drop"),
-            valid=cache.valid.at[idx].set(True, mode="drop"),
-        )
+    def _write_impl(self, cache, rows, idx):
+        """Scatter Δn precomputed rows (one per slab leaf before ``valid``)
+        into the slab and mark them live.  ``idx`` is bucket-padded with
+        ``capacity`` (out of range => dropped), so one trace serves every
+        Δn in the bucket."""
+        return cache._replace(
+            **{f: getattr(cache, f).at[idx].set(r, mode="drop")
+               for f, r in zip(cache._fields[:-1], rows)},
+            valid=cache.valid.at[idx].set(True, mode="drop"))
 
     def _drop_impl(self, cache, idx):
         return cache._replace(valid=cache.valid.at[idx].set(False,
                                                             mode="drop"))
 
     def _context_impl(self, params, ctx_ids, ctx_w):
-        """Per-query context cache: P_C (Bq, rho, k), s_C (Bq,), lin_C (Bq,)."""
-        from repro.models.recsys.fwfm import context_inputs
-        with serving_precision():
-            V_C, lin_C = context_inputs(params, self.cfg, ctx_ids, ctx_w)
-            p = DPLRParams(params["U"], params["e"])
-            ctx = rk.dplr_context_cache(p, V_C, self.cfg.layout.n_context)
-        return ctx.P_C, ctx.s_C, lin_C
+        """The model's query state for a batch of query rows (DPLR: the
+        context cache P_C, s_C, lin_C)."""
+        return self.model.query(params, ctx_ids, ctx_w)
 
     def _score_impl(self, params, cache, ctx_ids, ctx_w):
         self.trace_count += 1     # python side effect: runs at trace time only
-        P_C, s_C, lin_C = self._context_impl(params, ctx_ids, ctx_w)
-        # direct fused form — same reduction order as rank_items, so the
-        # corpus-cached path is float32-epsilon-close to the per-query
-        # path; the math lives in corpus.masked_slab_scores, shared with
-        # the sharded runtime so the two are bit-identical per slot.
-        return masked_slab_scores(params, cache.Q_I, cache.t_I, cache.lin_I,
-                                  cache.valid, P_C, s_C, lin_C)
+        return self.model.scores(params, cache,
+                                 self._context_impl(params, ctx_ids, ctx_w))
 
     def _topk_impl(self, params, cache, ctx_ids, ctx_w, *, K):
         scores = self._score_impl(params, cache, ctx_ids, ctx_w)
@@ -208,11 +198,8 @@ class ScorerRuntime:
         self.trace_count += 1     # python side effect: runs at trace time only
         vals, idx = [], []
         for s in range(len(params_parts)):
-            P_C, s_C, lin_C = self._context_impl(params_parts[s],
-                                                 ctx_ids[s], ctx_w[s])
-            c = cache_parts[s]
-            scores = masked_slab_scores(params_parts[s], c.Q_I, c.t_I,
-                                        c.lin_I, c.valid, P_C, s_C, lin_C)
+            q = self._context_impl(params_parts[s], ctx_ids[s], ctx_w[s])
+            scores = self.model.scores(params_parts[s], cache_parts[s], q)
             v, i = jax.lax.top_k(scores, K)
             vals.append(v)
             idx.append(i)
@@ -220,37 +207,25 @@ class ScorerRuntime:
 
     def _kernel_multi_topk_impl(self, params_parts, cache_parts, ctx_ids,
                                 ctx_w, *, K):
-        """Pallas fused multi-tenant scorer: ONE tenant-segmented kernel
-        launch (``kernels.dplr_corpus_score_multi``) covers every
-        segment's slab — the per-segment running top-K never mixes
-        tenants' slots."""
+        """Pallas fused multi-tenant scorer: every segment's query state,
+        then the model's multi-segment launch (DPLR: ONE tenant-segmented
+        kernel, ``kernels.dplr_corpus_score_multi``)."""
         self.trace_count += 1     # python side effect: runs at trace time only
-        from repro.kernels import ops as kops
-        pcs, acs, es = [], [], []
-        for s, params in enumerate(params_parts):
-            P_C, s_C, lin_C = self._context_impl(params, ctx_ids[s],
-                                                 ctx_w[s])
-            pcs.append(P_C)
-            acs.append(params["bias"] + lin_C + 0.5 * s_C)
-            es.append(params["e"])
-        return kops.dplr_corpus_score_multi(
-            tuple(c.Q_I for c in cache_parts),
-            tuple(c.a_I for c in cache_parts),
-            tuple(c.valid for c in cache_parts),
-            jnp.stack(es), jnp.stack(pcs), jnp.stack(acs),
-            topk=K, block_n=self.block_n)
+        qs = [self._context_impl(params, ctx_ids[s], ctx_w[s])
+              for s, params in enumerate(params_parts)]
+        return self.model.kernel_multi(params_parts, cache_parts, qs, K,
+                                       self.block_n)
 
     def _kernel_score_impl(self, params, cache, ctx_ids, ctx_w, *, K=None):
         """Pallas-backed scorer entry point — jitted at THIS level so
         ``trace_count`` tracks kernel-path retraces exactly like the jnp
-        path (a retrace here <=> a shape/static change for the kernel)."""
+        path (a retrace here <=> a shape/static change for the kernel),
+        and so the query state (MIND: the user tower) and the kernel run
+        in one program per launch."""
         self.trace_count += 1     # python side effect: runs at trace time only
-        from repro.kernels import ops as kops
-        P_C, s_C, lin_C = self._context_impl(params, ctx_ids, ctx_w)
-        a_C = params["bias"] + lin_C + 0.5 * s_C
-        return kops.dplr_corpus_score(cache.Q_I, cache.a_I, params["e"],
-                                      P_C, a_C, valid=cache.valid, topk=K,
-                                      block_n=self.block_n)
+        return self.model.kernel(params, cache,
+                                 self._context_impl(params, ctx_ids, ctx_w),
+                                 K, self.block_n)
 
     # -- sharded wiring -----------------------------------------------------
 
@@ -321,14 +296,14 @@ class ScorerRuntime:
             slots = np.concatenate([slots, np.full(pad, filler, np.int32)])
         return slots
 
-    def write_rows(self, params, cache, slots, ids, w) -> ItemCorpusCache:
+    def write_rows(self, params, cache, slots, ids, w):
         """Scatter Δn (slot -> item row) writes into ``cache`` and mark
         them live: ONE row-compute + scatter dispatch of O(Δn rho k)
         work, bucket-padded (power-of-two Δn).  Sharded: the delta is
         grouped per owning shard host-side first, so each device
         processes only its own rows.  Non-blocking (async dispatch)."""
         if self.mesh is None:
-            cap = cache.Q_I.shape[0]
+            cap = cache.valid.shape[0]
             dn = len(slots)
             slots_p = self._pad_slots(np.asarray(slots, np.int32), cap)
             pad = len(slots_p) - dn
@@ -337,19 +312,19 @@ class ScorerRuntime:
                     [ids, np.zeros((pad, ids.shape[1]), np.int32)])
                 w = np.concatenate([w, np.ones((pad, w.shape[1]),
                                                np.float32)])
-            Q, t, lin = self.rows(params, jnp.asarray(ids),
-                                  jnp.asarray(w, self.wdtype))
-            return self._write(cache, Q, t, lin, jnp.asarray(slots_p))
+            rows = self.rows(params, jnp.asarray(ids),
+                             jnp.asarray(w, self.wdtype))
+            return self._write(cache, rows, jnp.asarray(slots_p))
         from repro.serving import sharded
         li, ids_g, w_g = sharded.group_deltas(
             np.asarray(slots, np.int32), np.asarray(ids, np.int32),
-            np.asarray(w, np.float32), self._D, cache.Q_I.shape[0])
+            np.asarray(w, np.float32), self._D, cache.valid.shape[0])
         return self._write(params, cache, jnp.asarray(ids_g),
                            jnp.asarray(w_g, self.wdtype), jnp.asarray(li))
 
-    def drop_rows(self, cache, slots) -> ItemCorpusCache:
+    def drop_rows(self, cache, slots):
         """Invalidate slots (global ids, bucket-padded).  One scatter
         dispatch; mask-only, so no row compute.  Non-blocking."""
-        cap = cache.Q_I.shape[0] * (1 if self.mesh is None else self._D)
+        cap = cache.valid.shape[0] * (1 if self.mesh is None else self._D)
         slots_p = self._pad_slots(np.asarray(slots, np.int32), cap)
         return self._drop(cache, jnp.asarray(slots_p))

@@ -11,7 +11,16 @@ test it first and skip the span, because on the auction cells each
 microsecond of per-request host work costs a visible share of the
 median latency.  The ids land as the event's metadata: ``req`` (the
 RPC server's request sequence number) joins a request's spans to its
-``batch`` (the frontend's dispatch sequence number).
+``batch`` (the frontend's dispatch sequence number).  A model whose
+launch has work of its own on the host side carries a span around its
+enqueue: ``engine.mind_launch`` (MIND: the user tower and the corpus
+kernel enqueued as one program), labelled with the ``batch``.
+
+Counters.  ``QueryFrontend.stats`` counts, per launch, the query
+vectors the kernel scans (``scan_vectors``: DPLR-FwFM one a row, MIND
+K = 4 a row, padding rows included) and those that carry a live
+interest (``live_vectors``: MIND's K'_u of each row), from
+``CorpusState.query_vectors``.
 
 Stages.  ``Stages`` keeps one histogram per stage of ``STAGES``: counts
 in fixed log-spaced buckets of relative width 2**(1/8), from 1 us to
@@ -45,7 +54,7 @@ STAGES = ("rpc", "queue", "inflight", "server", "read_block",
 LO = 1e-6                    # seconds: lower edge of bucket 0
 PER_OCTAVE = 8               # buckets per doubling: width 2**(1/8)
 N_BUCKETS = math.ceil(PER_OCTAVE * math.log2(100.0 / LO))   # up to 100 s
-_OFF = contextlib.nullcontext()
+OFF = contextlib.nullcontext()       # the shared no-op span
 tracing = jax.profiler.TraceAnnotation.is_enabled   # a session records
 
 
@@ -54,7 +63,7 @@ def span(name: str, **ids):
     unless a profiler session is recording."""
     if tracing():
         return jax.profiler.TraceAnnotation(name, **ids)
-    return _OFF
+    return OFF
 
 
 def bucket(seconds: float) -> int:

@@ -56,6 +56,7 @@ def test_recsys_smoke_train_step(arch, rng):
     if arch == "mind":
         item_vocab = lay.fields[-1].vocab_size
         batch = {
+            "profile_ids": jnp.asarray(rng.integers(0, 16, (B, lay.n_context)).astype(np.int32)),
             "hist_ids": jnp.asarray(rng.integers(0, item_vocab, (B, cfg.seq_len)).astype(np.int32)),
             "hist_mask": jnp.ones((B, cfg.seq_len), jnp.float32),
             "target_id": jnp.asarray(rng.integers(0, item_vocab, B).astype(np.int32)),

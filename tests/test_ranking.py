@@ -140,11 +140,13 @@ def test_bst_and_mind_rank(rng):
     item_vocab = mcfg.layout.fields[-1].vocab_size
     histm = jnp.asarray(rng.integers(0, item_vocab, (B, mcfg.seq_len)).astype(np.int32))
     hm = jnp.ones((B, mcfg.seq_len), jnp.float32)
-    qm = {"hist_ids": histm, "hist_mask": hm,
+    prof = jnp.asarray(rng.integers(0, 16, (B, mcfg.layout.n_context)).astype(np.int32))
+    qm = {"profile_ids": prof, "hist_ids": histm, "hist_mask": hm,
           "item_ids": jnp.asarray(rng.integers(0, item_vocab, (B, N, 1)).astype(np.int32))}
     sm = mind.rank_items(mp, mcfg, qm)
     refm = jnp.stack([
-        mind.apply(mp, mcfg, {"hist_ids": histm, "hist_mask": hm,
+        mind.apply(mp, mcfg, {"profile_ids": prof, "hist_ids": histm,
+                              "hist_mask": hm,
                               "target_id": qm["item_ids"][:, j, 0]})
         for j in range(N)], 1)
     np.testing.assert_allclose(sm, refm, rtol=1e-4, atol=1e-4)

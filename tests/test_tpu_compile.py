@@ -1,6 +1,7 @@
 """The main-path Pallas kernels compile for a TPU v5e at the deployed
 widths (rank 3, k = 16, 38 item fields, a corpus of 8192 items, and the
-retrieval slab of 2^20 slots).
+retrieval slab of 2^20 slots; MIND's slab of 2^24 slots of d = 64 with 4
+interests).
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached, so these tests run on the CPU: each lowers a
@@ -26,6 +27,7 @@ from repro.kernels import ops
 
 RHO, K_EMB, M_I, N, TOPK = 3, 16, 38, 8192, 16
 N_RETRIEVAL = 1 << 20
+N_MIND, D_MIND, K_MIND = 1 << 24, 64, 4
 
 
 @pytest.fixture(scope="module")
@@ -113,6 +115,24 @@ def test_corpus_multi_compiles_for_v5e(one_chip, no_compile_cache):
         one_chip, ((N, RHO, K_EMB), f32), ((N,), f32), ((N,), jnp.bool_),
         ((S, RHO), f32), ((S, Bq, RHO, K_EMB), f32), ((S, Bq), f32),
         kernel="dplr_corpus_score_multi_topk")
+
+
+@pytest.mark.parametrize("Bq", [1, 16])
+def test_mind_topk_compiles_for_v5e(one_chip, no_compile_cache, Bq):
+    """MIND's slab (2^24 slots, d 64, K 4 interests, top-16) compiles at
+    the VMEM rule's tile, and enters the kernel as stored: the TPU keeps
+    an (n, 64) f32 array items-minor, so the kernel's (d, n) view is a
+    bitcast and no [2^24, 64] copy is made on a launch."""
+    f32 = jnp.float32
+    text = _assert_compiles(
+        lambda E, U, live, v: ops.mind_corpus_score(
+            E, U, live, v, topk=TOPK, interpret=False),
+        one_chip, ((N_MIND, D_MIND), f32), ((Bq, K_MIND, D_MIND), f32),
+        ((Bq, K_MIND), jnp.bool_), ((N_MIND,), jnp.bool_),
+        kernel="mind_corpus_score_topk")
+    slab_copies = re.findall(rf"\[{N_MIND},{D_MIND}\]\{{[^}}]*\}} copy\(",
+                             text)
+    assert not slab_copies, slab_copies
 
 
 def test_score_items_compiles_for_v5e(one_chip, no_compile_cache):
